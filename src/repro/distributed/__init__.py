@@ -1,4 +1,3 @@
-from . import compat  # noqa: F401  — installs jax.sharding shims on import
 from .ctx import activation_sharding, logical_pspec, shard_act
 from .sharding import (batch_shardings, cache_shardings, default_rules,
                        param_shardings, replicated)

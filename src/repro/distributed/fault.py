@@ -26,6 +26,8 @@ import statistics
 import time
 from typing import Any, Callable, Dict, List, Optional
 
+import jax
+
 from .checkpoint import latest_step, restore_checkpoint, save_checkpoint
 
 
@@ -99,17 +101,29 @@ class RetryPolicy:
     backoff: float = 2.0
 
 
+def _inputs_consumed(args, kwargs) -> bool:
+    """True when a call has deleted one of its array inputs (a jitted step
+    that donates them): calling it again could only fail on the deleted
+    buffers and would hide the error that mattered."""
+    return any(getattr(leaf, "is_deleted", lambda: False)()
+               for leaf in jax.tree_util.tree_leaves((args, kwargs)))
+
+
 def retry_with_backoff(fn: Callable, *args, policy: RetryPolicy = RetryPolicy(),
                        retryable=(RuntimeError, OSError), on_retry=None,
                        sleep=time.sleep, **kwargs):
     """Call ``fn``; on a retryable exception, back off exponentially and
-    retry up to ``policy.retries`` times, then re-raise the last error."""
+    retry up to ``policy.retries`` times, then re-raise the last error.
+
+    A failed call that consumed (donated) its inputs is not retried: its
+    error is raised as it is.
+    """
     delay = policy.base_delay
     for attempt in range(policy.retries + 1):
         try:
             return fn(*args, **kwargs)
         except retryable as e:
-            if attempt == policy.retries:
+            if attempt == policy.retries or _inputs_consumed(args, kwargs):
                 raise
             if on_retry is not None:
                 on_retry(attempt, e, delay)
@@ -253,6 +267,7 @@ class FaultTolerantLoop:
     """Checkpointed training loop with auto-resume.
 
     step_fn(state, batch) -> (state, metrics); state is any pytree.
+    ``ckpt_dir=None`` runs the loop without checkpoints.
     """
 
     def __init__(
@@ -275,7 +290,7 @@ class FaultTolerantLoop:
         self.preemption = preemption
         self.start_step = 0
         self.state = state
-        prev = latest_step(ckpt_dir)
+        prev = latest_step(ckpt_dir) if ckpt_dir is not None else None
         if prev is not None:
             self.start_step, self.state = restore_checkpoint(
                 ckpt_dir, state, shardings=shardings)
@@ -295,10 +310,14 @@ class FaultTolerantLoop:
                 on_metrics(step, metrics)
             must_stop = self.preemption is not None and self.preemption.requested
             if step % self.ckpt_every == self.ckpt_every - 1 or must_stop:
-                save_checkpoint(self.ckpt_dir, step, self.state, keep=self.keep)
+                self._save(step)
             if must_stop:
                 return step + 1
             step += 1
         if step > self.start_step:
-            save_checkpoint(self.ckpt_dir, step - 1, self.state, keep=self.keep)
+            self._save(step - 1)
         return step
+
+    def _save(self, step: int) -> None:
+        if self.ckpt_dir is not None:
+            save_checkpoint(self.ckpt_dir, step, self.state, keep=self.keep)

@@ -17,7 +17,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -83,11 +82,11 @@ def make_pipelined_forward(block_fn: Callable, mesh: Mesh, stage_axis: str,
         sp = jax.tree_util.tree_map(lambda a: a[0], stage_params)
         return pipeline_stage_fn(block_fn, n_stages, stage_axis)(sp, xs)
 
-    return shard_map(
+    return jax.shard_map(
         per_device, mesh=mesh,
         in_specs=(param_spec, x_spec),
         out_specs=x_spec,
-        check_rep=False,
+        check_vma=False,
     )
 
 

@@ -30,7 +30,7 @@ NEG_INF = -1e30
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, prof_ref, *, kv_blk: int,
                       scale: float, causal: bool, profile: bool):
     """One (q_block × all kv_blocks) pass.  Shapes (per block):
-    q_ref [q_blk, d]; k_ref/v_ref [S, d]; o_ref [q_blk, d]; prof_ref [1]."""
+    q_ref [q_blk, d]; k_ref/v_ref [S, d]; o_ref [q_blk, d]; prof_ref [1, n_q]."""
     q_blk, d = q_ref.shape
     S = k_ref.shape[0]
     qi = pl.program_id(1)
@@ -47,8 +47,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, prof_ref, *, kv_blk: int,
 
     def body(j, carry):
         m, l, acc = carry
-        k = pl.load(k_ref, (pl.dslice(j * kv_blk, kv_blk), slice(None)))
-        v = pl.load(v_ref, (pl.dslice(j * kv_blk, kv_blk), slice(None)))
+        k = k_ref[pl.ds(j * kv_blk, kv_blk), :]
+        v = v_ref[pl.ds(j * kv_blk, kv_blk), :]
         s = q @ k.astype(jnp.float32).T                     # [q_blk, kv_blk]
         if causal:
             q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
@@ -70,7 +70,10 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, prof_ref, *, kv_blk: int,
     o_ref[...] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
     if profile:
         # in-band record: running max logit of this (head, q_block)
-        prof_ref[0] = jnp.max(m)
+        # (the [1, n_q] row is revisited by every q block of this head;
+        # each block fills only its own lane)
+        lane = jax.lax.broadcasted_iota(jnp.int32, prof_ref.shape, 1)
+        prof_ref[...] = jnp.where(lane == qi, jnp.max(m), prof_ref[...])
 
 
 def flash_attention(
@@ -108,11 +111,11 @@ def flash_attention(
         ],
         out_specs=[
             pl.BlockSpec((None, q_blk, D), lambda h, i: (h, i, 0)),
-            pl.BlockSpec((None, 1), lambda h, i: (h, i)),
+            pl.BlockSpec((None, 1, n_q), lambda h, i: (h, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
-            jax.ShapeDtypeStruct((B * H, n_q), jnp.float32),
+            jax.ShapeDtypeStruct((B * H, 1, n_q), jnp.float32),
         ],
         interpret=interpret,
     )(q.reshape(B * H, T, D), k.reshape(B * H, S, D), v.reshape(B * H, S, D))

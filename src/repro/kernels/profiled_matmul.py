@@ -22,7 +22,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 def _matmul_kernel(a_ref, b_ref, o_ref, prof_ref, acc_ref, *, n_k: int,
                    profile: bool):
-    k = pl.program_id(2)
+    j, k = pl.program_id(1), pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
@@ -37,8 +37,12 @@ def _matmul_kernel(a_ref, b_ref, o_ref, prof_ref, acc_ref, *, n_k: int,
         acc = acc_ref[...]
         o_ref[...] = acc.astype(o_ref.dtype)
         if profile:
-            # in-band profile word: absmax of this output tile
-            prof_ref[0, 0] = jnp.max(jnp.abs(acc))
+            # in-band profile word: absmax of this output tile, written
+            # into lane j of the [1, N/bn] row that every tile of row
+            # block i revisits
+            lane = jax.lax.broadcasted_iota(jnp.int32, prof_ref.shape, 1)
+            prof_ref[...] = jnp.where(lane == j, jnp.max(jnp.abs(acc)),
+                                      prof_ref[...])
 
 
 def profiled_matmul(
@@ -70,13 +74,13 @@ def profiled_matmul(
         ],
         out_specs=[
             pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j, k: (i, j)),
+            pl.BlockSpec((None, 1, N // bn), lambda i, j, k: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((M, N), a.dtype),
-            jax.ShapeDtypeStruct((M // bm, N // bn), jnp.float32),
+            jax.ShapeDtypeStruct((M // bm, 1, N // bn), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
     )(a, b)
-    return out, (prof if profile else None)
+    return out, (prof.reshape(M // bm, N // bn) if profile else None)

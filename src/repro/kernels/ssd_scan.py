@@ -25,8 +25,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 def _state_passing_kernel(s_ref, decay_ref, out_ref, carry_ref, *,
                           n_chunks: int):
-    """Blocks: s_ref [1, HB, P, N] (chunk c), decay_ref [1, HB],
-    out_ref [1, HB, P, N], carry_ref (scratch) [HB, P, N]."""
+    """Blocks: s_ref [1, HB, P, N] (chunk c), decay_ref [NC, HB] (SMEM,
+    every chunk's decays), out_ref [1, HB, P, N], carry_ref (scratch)
+    [HB, P, N]."""
     c = pl.program_id(1)
 
     @pl.when(c == 0)
@@ -35,9 +36,9 @@ def _state_passing_kernel(s_ref, decay_ref, out_ref, carry_ref, *,
 
     running = carry_ref[...]
     out_ref[0] = running.astype(out_ref.dtype)
-    dec = decay_ref[0]                                   # [HB]
-    s_c = s_ref[0].astype(jnp.float32)                   # [HB, P, N]
-    carry_ref[...] = dec[:, None, None] * running + s_c
+    for h in range(carry_ref.shape[0]):                  # scalar decay per head
+        carry_ref[h] = (decay_ref[c, h] * running[h]
+                        + s_ref[0, h].astype(jnp.float32))
 
 
 def ssd_state_passing(
@@ -67,7 +68,8 @@ def ssd_state_passing(
         grid=(B * (H // hb), NC),
         in_specs=[
             pl.BlockSpec((None, 1, hb, P, N), lambda b, c: (b, c, 0, 0, 0)),
-            pl.BlockSpec((None, 1, hb), lambda b, c: (b, c, 0)),
+            pl.BlockSpec((None, NC, hb), lambda b, c: (b, 0, 0),
+                         memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((None, 1, hb, P, N),
                                lambda b, c: (b, c, 0, 0, 0)),

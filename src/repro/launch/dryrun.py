@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
 This is the proof that the distribution config is coherent without real
@@ -20,6 +17,7 @@ import argparse
 import dataclasses
 import gzip
 import json
+import os
 import time
 import traceback
 from pathlib import Path
@@ -35,6 +33,7 @@ from repro.distributed import (
     activation_sharding, batch_shardings, cache_shardings, default_rules,
     param_shardings, replicated,
 )
+from repro.launch.compile_cache import configure_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.models import abstract_params
 from repro.models.api import model_specs
@@ -249,6 +248,9 @@ def run_cell(arch, cell_name, mesh_kind, variant="base",
 
 
 def main():
+    # the 512 virtual CPU devices of the production meshes; set before the
+    # first device query initialises the backend
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS)
     ap.add_argument("--cell", choices=[c.name for c in SHAPE_CELLS])
@@ -275,7 +277,7 @@ def main():
             v = v == "True"
         overrides[k] = v
 
-    jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
+    configure_compile_cache()
 
     if args.all:
         archs = [args.arch] if args.arch else ARCH_IDS

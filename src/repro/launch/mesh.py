@@ -9,11 +9,9 @@ DCN; everything else stays inside a pod's ICI).
 from __future__ import annotations
 
 import math
-from typing import Optional
+from typing import Optional, Sequence
 
 import jax
-
-from repro.distributed import compat as _compat  # noqa: F401  — AxisType shim
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -26,17 +24,18 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"mesh {shape} needs {need} devices but only {len(devices)} are "
             f"visible — the dry-run entrypoint must set "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={need} before "
-            f"any jax import")
+            f"the first device query")
     return jax.make_mesh(
         shape, axes, devices=devices[:need],
         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
-def make_host_mesh(model: Optional[int] = None):
-    """Small mesh over whatever devices exist (tests on CPU)."""
-    n = len(jax.devices())
+def make_host_mesh(model: Optional[int] = None,
+                   devices: Optional[Sequence] = None):
+    """(data, model) mesh over ``devices`` (default: every visible device)."""
+    devices = list(jax.devices() if devices is None else devices)
     model = model or 1
-    data = n // model
+    data = len(devices) // model
     return jax.make_mesh(
-        (data, model), ("data", "model"), devices=jax.devices()[: data * model],
+        (data, model), ("data", "model"), devices=devices[: data * model],
         axis_types=(jax.sharding.AxisType.Auto,) * 2)

@@ -4,7 +4,9 @@ Greedy-decodes a batch of prompts with the family-appropriate cache
 machinery; the SPRING stream reports per-step cache occupancy and attention
 logit maxima.  The profiling path runs under a ``ProfilingSupervisor``: a
 watchdog + integrity verification degrade it gracefully (inline → shortcut →
-off) on repeated faults while the token path keeps serving.  CPU example:
+off) on repeated faults while the token path keeps serving.  The default
+architecture, chatglm3-6b, fits one 16 GB chip at its published widths.
+CPU example (``--reduced`` swaps in the d_model-64 smoke preset):
 
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2.5-14b --reduced \\
       --batch 4 --prompt-len 16 --gen 16
@@ -35,6 +37,8 @@ class ServeResult:
     supervisor: ProfilingSupervisor
     watchdog: Watchdog
     toks_per_s: float
+    compile_s: float      # ahead-of-time compile of the serve step
+    step_s: float         # wall seconds per generated step after the first
 
 
 def _profile_step(policy: str, pos: int, max_len: int) -> ProfileStream:
@@ -58,7 +62,7 @@ def _profile_step(policy: str, pos: int, max_len: int) -> ProfileStream:
 
 
 def run_serve(
-    arch: str = "qwen2.5-14b", *, reduced: bool = True, batch: int = 4,
+    arch: str = "chatglm3-6b", *, reduced: bool = False, batch: int = 4,
     prompt_len: int = 16, gen: int = 16, seed: int = 0,
     profile_policy: str = "inline", failure_threshold: int = 2,
     overhead_budget: float = 0.25, step_budget_s: float = 5.0,
@@ -85,8 +89,10 @@ def run_serve(
         jax.random.PRNGKey(seed + 1), (batch, prompt_len),
         0, cfg.vocab_size, jnp.int32)
 
-    serve_step = jax.jit(make_serve_step(cfg), donate_argnums=(1,),
-                         static_argnums=())
+    t0 = time.perf_counter()
+    serve_step = jax.jit(make_serve_step(cfg), donate_argnums=(1,)).lower(
+        params, caches, prompts[:, :1], 0).compile()
+    compile_s = time.perf_counter() - t0
     collector = ProfileCollector()
     if trace:
         # kv/occupancy words are [used_positions, cache_len]: the cache is
@@ -100,14 +106,20 @@ def run_serve(
 
     # prefill by streaming prompt tokens through the decode path (family-
     # uniform; attention archs could use the fused prefill_fn instead)
-    t0 = time.time()
+    t0 = time.perf_counter()
     for pos in range(prompt_len - 1):
         nxt, caches, rows = retry_with_backoff(
             serve_step, params, caches, prompts[:, pos:pos + 1], pos,
             policy=retry)
     generated = [prompts]
     tok = prompts[:, -1:]
+    t_gen = None
     for step_i, pos in enumerate(range(prompt_len - 1, max_len - 1)):
+        if step_i == 1:
+            # the first generated step also compiled the profiling path's
+            # host-side ops; time the steady state from here
+            jax.block_until_ready(tok)
+            t_gen = time.perf_counter()
         t_step = time.time()
         tok, caches, rows = retry_with_backoff(
             serve_step, params, caches, tok, pos, policy=retry)
@@ -128,7 +140,8 @@ def run_serve(
                 (time.time() - t_prof) / max(dt_step, 1e-9))
         else:
             supervisor.step_ok()
-    dt = time.time() - t0
+    jax.block_until_ready(tok)
+    t_end = time.perf_counter()
 
     if trace and collector.trace is not None:
         for ev in supervisor.events:
@@ -140,13 +153,16 @@ def run_serve(
     out = jnp.concatenate(generated, axis=1)
     return ServeResult(
         tokens=out, collector=collector, supervisor=supervisor,
-        watchdog=watchdog, toks_per_s=batch * (max_len - 1) / dt)
+        watchdog=watchdog, toks_per_s=batch * (max_len - 1) / (t_end - t0),
+        compile_s=compile_s,
+        step_s=(t_end - t_gen) / (gen - 1) if gen > 1 else float("nan"))
 
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", choices=ARCH_IDS, default="qwen2.5-14b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--arch", choices=ARCH_IDS, default="chatglm3-6b")
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the reduced (smoke) config — CPU-friendly")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--gen", type=int, default=16)

@@ -55,11 +55,27 @@ def is_spec(x) -> bool:
     return isinstance(x, ParamSpec)
 
 
-def init_params(specs, key):
-    """Concrete init: one fresh key per leaf, deterministic in tree order."""
+_init_leaf_jit = jax.jit(_init_leaf, static_argnums=0)
+
+
+def init_params(specs, key, shardings=None):
+    """Concrete init: one fresh key per leaf, deterministic in tree order.
+
+    Each leaf is made by its own jitted program, so its float32 draw fuses
+    into the cast and no float32 copy of a whole leaf is ever live (eager
+    draws held two at once: more than one chip has for the largest leaf of
+    a 6B model).  ``shardings`` (a tree from :func:`shardings_for`) places
+    every leaf where it is made, so a model larger than one device never
+    lands on one.
+    """
     leaves, treedef = jax.tree_util.tree_flatten(specs, is_leaf=is_spec)
     keys = jax.random.split(key, len(leaves))
-    vals = [_init_leaf(s, k) for s, k in zip(leaves, keys)]
+    if shardings is None:
+        inits = [_init_leaf_jit] * len(leaves)
+    else:
+        inits = [jax.jit(_init_leaf, static_argnums=0, out_shardings=sh)
+                 for sh in treedef.flatten_up_to(shardings)]
+    vals = [init(s, k) for init, s, k in zip(inits, leaves, keys)]
     return jax.tree_util.tree_unflatten(treedef, vals)
 
 

@@ -87,7 +87,7 @@ def test_static_seed_clears_deadlock_with_zero_attempts():
     assert attempts0
 
 
-@settings(max_examples=8)
+@settings(deadline=None, max_examples=8)
 @given(st.integers(0, 10_000), st.integers(3, 7),
        st.sampled_from(["density", "short_skip", "long_skip", "ends_only"]),
        st.integers(0, 3))
@@ -104,7 +104,7 @@ def test_safe_verdict_never_deadlocks(seed, depth, pattern, slack):
     assert res.completed and res.cycles == an.predicted_cycles
 
 
-@settings(max_examples=6)
+@settings(deadline=None, max_examples=6)
 @given(st.integers(0, 10_000), st.integers(4, 7))
 def test_deadlock_verdict_implies_stall(seed, depth):
     """Property: a ``deadlock`` verdict is a guarantee — the run must not
@@ -127,7 +127,7 @@ def test_deadlock_verdict_implies_stall(seed, depth):
     assert not res.completed
 
 
-@settings(max_examples=6)
+@settings(deadline=None, max_examples=6)
 @given(st.integers(0, 10_000),
        st.sampled_from(["density", "long_skip", "ends_only"]))
 def test_static_bound_never_exceeds_trace_recommendation(seed, pattern):

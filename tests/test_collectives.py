@@ -2,6 +2,7 @@
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
 
 
 SCRIPT = textwrap.dedent("""
@@ -12,7 +13,6 @@ SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp
     import numpy as np
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.distributed.collectives import compressed_mean, quantize_int8
 
     mesh = jax.make_mesh((8,), ("pod",), devices=jax.devices(),
@@ -21,8 +21,8 @@ SCRIPT = textwrap.dedent("""
     def f(x):
         return compressed_mean(x, "pod")
 
-    g = shard_map(f, mesh=mesh, in_specs=P("pod", None),
-                  out_specs=P("pod", None), check_rep=False)
+    g = jax.shard_map(f, mesh=mesh, in_specs=P("pod", None),
+                      out_specs=P("pod", None), check_vma=False)
     x = jax.random.normal(jax.random.PRNGKey(0), (8, 64)) * 3.0
     jitted = jax.jit(g)
     out = jitted(x)
@@ -47,7 +47,7 @@ def test_compressed_mean_wire_level_int8(tmp_path):
     script = tmp_path / "wire_test.py"
     script.write_text(SCRIPT)
     res = subprocess.run(
-        [sys.executable, str(script)], cwd="/root/repo",
+        [sys.executable, str(script)], cwd=Path(__file__).resolve().parents[1],
         capture_output=True, text=True, timeout=600)
     assert res.returncode == 0, res.stdout + res.stderr
     assert "OK wire-level int8 all-gather verified" in res.stdout

@@ -385,6 +385,28 @@ def test_retry_with_backoff_exhausts_and_raises():
                            sleep=lambda _d: None)
 
 
+def test_retry_with_backoff_raises_first_error_of_donating_step():
+    """A failed step that deleted (donated) its inputs is not retried: the
+    device error it raised is the one the caller sees."""
+    import jax
+    import jax.numpy as jnp
+
+    calls = {"n": 0}
+
+    def donating_step(buf):
+        calls["n"] += 1
+        if buf.is_deleted():
+            raise RuntimeError("Array has been deleted")
+        buf.delete()  # what a jitted step with donate_argnums does
+        raise jax.errors.JaxRuntimeError("INTERNAL: device fault")
+
+    with pytest.raises(jax.errors.JaxRuntimeError, match="device fault"):
+        retry_with_backoff(donating_step, jnp.ones(4),
+                           policy=RetryPolicy(retries=3),
+                           sleep=lambda _d: None)
+    assert calls["n"] == 1
+
+
 def test_watchdog_counts_consecutive_breaches():
     wd = Watchdog(budget_s=1.0)
     assert not wd.observe(0.5)
@@ -425,8 +447,8 @@ def test_supervisor_overhead_budget_trigger():
 def test_serve_degrades_profiling_but_keeps_producing_tokens():
     from repro.launch.serve import run_serve
 
-    res = run_serve("qwen2.5-14b", batch=2, prompt_len=4, gen=6,
-                    corrupt_every=1, failure_threshold=2)
+    res = run_serve("qwen2.5-14b", reduced=True, batch=2, prompt_len=4,
+                    gen=6, corrupt_every=1, failure_threshold=2)
     # tokens kept flowing to the very end
     assert res.tokens.shape == (2, 10)
     # the ladder walked all the way down under sustained corruption
@@ -439,7 +461,8 @@ def test_serve_degrades_profiling_but_keeps_producing_tokens():
 def test_serve_clean_run_never_degrades():
     from repro.launch.serve import run_serve
 
-    res = run_serve("qwen2.5-14b", batch=2, prompt_len=4, gen=4)
+    res = run_serve("qwen2.5-14b", reduced=True, batch=2, prompt_len=4,
+                    gen=4)
     assert res.tokens.shape == (2, 8)
     assert res.supervisor.policy == "inline"
     assert res.supervisor.events == []

@@ -104,7 +104,7 @@ def test_certificate_confirm_rejects_wrong_state():
     assert not wrong.confirm(sim)
 
 
-@settings(max_examples=10)
+@settings(deadline=None, max_examples=10)
 @given(st.integers(0, 10_000), st.integers(3, 7),
        st.sampled_from(["density", "short_skip", "long_skip", "ends_only"]))
 def test_checker_agrees_with_simulator_on_random_maps(seed, depth, pattern):
@@ -128,7 +128,7 @@ def test_checker_agrees_with_simulator_on_random_maps(seed, depth, pattern):
         assert dec.certificate.confirm(sim)
 
 
-@settings(max_examples=6)
+@settings(deadline=None, max_examples=6)
 @given(st.integers(0, 10_000), st.integers(3, 6))
 def test_checker_agrees_with_simulator_profiled(seed, depth):
     """Property: ditto under Listing-2 profiling interference (the replay

@@ -230,3 +230,55 @@ def test_profiling_off_changes_no_math():
     l_off, (_, rows_off) = lm_loss(cfg_off, params, toks, toks)
     assert float(l_on) == pytest.approx(float(l_off), rel=1e-6)
     assert rows_off.shape[-1] == 0
+
+
+# --------------------------------------------------------------------- #
+# parameter init
+# --------------------------------------------------------------------- #
+def _eager_leaf(spec, key):
+    """The per-leaf draw done op by op: float32 normal, scale, cast."""
+    import math
+    if spec.init == "zeros":
+        return jnp.zeros(spec.shape, spec.dtype)
+    if spec.init == "ones":
+        return jnp.ones(spec.shape, spec.dtype)
+    std = (spec.scale if spec.scale is not None
+           else 1.0 / math.sqrt(max(1, spec.fan_in)))
+    return (jax.random.normal(key, spec.shape, jnp.float32) * std
+            ).astype(spec.dtype)
+
+
+@pytest.mark.parametrize("arch", ["chatglm3-6b", "qwen2.5-14b"])
+def test_init_params_bit_identical_to_eager_draws(arch):
+    from repro.configs import get_config
+    from repro.models.api import model_specs
+    from repro.models.params import is_spec
+
+    specs = model_specs(get_config(arch).reduced())
+    key = jax.random.PRNGKey(3)
+    got = init_params(specs, key)
+    leaves, treedef = jax.tree_util.tree_flatten(specs, is_leaf=is_spec)
+    keys = jax.random.split(key, len(leaves))
+    for spec, k, g in zip(leaves, keys, treedef.flatten_up_to(got)):
+        want = _eager_leaf(spec, k)
+        assert g.dtype == want.dtype and g.shape == want.shape
+        np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                      np.asarray(want, np.float32))
+
+
+def test_init_params_places_leaves_by_sharding():
+    from repro.configs import get_config
+    from repro.distributed import default_rules, param_shardings
+    from repro.launch.mesh import make_host_mesh
+    from repro.models.api import model_specs
+
+    specs = model_specs(get_config("chatglm3-6b").reduced())
+    shardings = param_shardings(specs, make_host_mesh(), default_rules())
+    placed = init_params(specs, jax.random.PRNGKey(3), shardings)
+    plain = init_params(specs, jax.random.PRNGKey(3))
+    for p, s, q in zip(jax.tree_util.tree_leaves(placed),
+                       jax.tree_util.tree_leaves(shardings),
+                       jax.tree_util.tree_leaves(plain)):
+        assert p.sharding == s
+        np.testing.assert_array_equal(np.asarray(p, np.float32),
+                                      np.asarray(q, np.float32))

@@ -2,6 +2,7 @@
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
 
 import pytest
 
@@ -55,7 +56,7 @@ def test_pipeline_matches_sequential(tmp_path):
     script = tmp_path / "pp_test.py"
     script.write_text(SCRIPT)
     res = subprocess.run(
-        [sys.executable, str(script)], cwd="/root/repo",
+        [sys.executable, str(script)], cwd=Path(__file__).resolve().parents[1],
         capture_output=True, text=True, timeout=900)
     assert res.returncode == 0, res.stdout[-2000:] + res.stderr[-3000:]
     assert "OK pipeline matches sequential" in res.stdout

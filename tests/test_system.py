@@ -108,3 +108,55 @@ def test_input_specs_cover_every_cell():
             if cell.kind != "decode":
                 tokens_like = leaves[0]
                 assert tokens_like.shape[0] == cell.global_batch
+
+
+def test_compile_cache_defaults_to_fixed_path_in_checkout(monkeypatch):
+    from repro.launch.compile_cache import configure_compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        path = configure_compile_cache()
+        assert path == str(Path(__file__).resolve().parents[1]
+                           / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_compile_cache_is_written_where_env_says(tmp_path):
+    import os
+    import subprocess
+    import sys
+
+    root = Path(__file__).resolve().parents[1]
+    script = (
+        "import jax, jax.numpy as jnp\n"
+        "from repro.launch.compile_cache import configure_compile_cache\n"
+        "print(configure_compile_cache())\n"
+        "jax.config.update('jax_persistent_cache_min_compile_time_secs', 0)\n"
+        "jax.jit(lambda x: x * 2 + 1)(jnp.ones(8)).block_until_ready()\n")
+    cache = tmp_path / "cache"
+    env = {**os.environ, "JAX_COMPILATION_CACHE_DIR": str(cache),
+           "JAX_PLATFORMS": "cpu", "PYTHONPATH": str(root / "src")}
+    res = subprocess.run([sys.executable, "-c", script], env=env, cwd=root,
+                         capture_output=True, text=True, timeout=300)
+    assert res.returncode == 0, res.stderr
+    assert res.stdout.split() == [str(cache)]
+    assert any(cache.iterdir())
+
+
+def test_dryrun_import_leaves_xla_flags_alone():
+    import os
+    import subprocess
+    import sys
+
+    root = Path(__file__).resolve().parents[1]
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=str(root / "src"))
+    res = subprocess.run(
+        [sys.executable, "-c",
+         "import os, repro.launch.dryrun; print(os.environ.get('XLA_FLAGS'))"],
+        env=env, cwd=root, capture_output=True, text=True, timeout=300)
+    assert res.returncode == 0, res.stderr
+    assert res.stdout.split() == ["None"]
