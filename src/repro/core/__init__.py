@@ -7,7 +7,8 @@ module (see DESIGN.md §2 for the FPGA→TPU mapping).
 """
 from .stream import (
     GUARD_ALGOS, INTEGRITY_METRIC, IntegrityReport, Label, PLACEHOLDER,
-    ProfileStream, placeholder_label, validate_policy,
+    ProfileStream, placeholder_label, reset_stream_stats, stream_stats,
+    validate_policy,
 )
 from .tape import TapeSpec, concat_streams_and_rows, rows_to_stream
 from .codec import (
@@ -21,6 +22,7 @@ from . import metrics
 __all__ = [
     "Label", "PLACEHOLDER", "ProfileStream", "placeholder_label", "validate_policy",
     "GUARD_ALGOS", "INTEGRITY_METRIC", "IntegrityReport",
+    "stream_stats", "reset_stream_stats",
     "TapeSpec", "concat_streams_and_rows", "rows_to_stream",
     "FLOAT_FORMATS", "FixedPointCodec", "verify_checksum", "verify_crc32",
     "word_checksum", "word_crc32",

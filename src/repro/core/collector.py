@@ -74,6 +74,13 @@ class ProfileCollector:
         poisons one signal for one step instead of the whole collection run.
         """
         decoded, report = stream.decode_verified()
+        self.fold_verified(decoded, report)
+        return decoded, report
+
+    def fold_verified(self, decoded: Dict[str, np.ndarray],
+                      report: IntegrityReport) -> None:
+        """The host-only half of :meth:`ingest_verified`: fold the output
+        of ``ProfileStream.decode_verified`` and count its damage."""
         self.ingest_decoded(decoded)
         self._last_integrity = report
         if not report.ok:
@@ -81,7 +88,6 @@ class ProfileCollector:
             for name in report.quarantined:
                 self.quarantine_counts[name] = (
                     self.quarantine_counts.get(name, 0) + 1)
-        return decoded, report
 
     @property
     def last_integrity(self) -> Optional[IntegrityReport]:

@@ -64,6 +64,28 @@ _NON_SIGNAL_METRICS = ("placeholder", INTEGRITY_METRIC)
 # either (or both) algorithms decode without any mode flag.
 GUARD_ALGOS = ("xor24", "crc32")
 
+# Host-side decode counters: ``reads`` counts device-to-host reads
+# (``jax.device_get``), ``streams`` the streams decoded.
+_STATS = {"reads": 0, "streams": 0}
+
+
+def stream_stats() -> Dict[str, int]:
+    """Decode counters: each ``decode`` / ``decode_verified`` adds one to
+    ``streams`` and one to ``reads`` per device-to-host read it makes (the
+    stream's words, then one per guard checksum it recomputes)."""
+    return dict(_STATS)
+
+
+def reset_stream_stats() -> None:
+    for k in _STATS:
+        _STATS[k] = 0
+
+
+def _read(x) -> np.ndarray:
+    """One counted device-to-host read, as float64."""
+    _STATS["reads"] += 1
+    return np.asarray(jax.device_get(x), dtype=np.float64)
+
 
 @dataclasses.dataclass(frozen=True)
 class Label:
@@ -256,7 +278,8 @@ class ProfileStream:
         Runs host-side on concrete arrays (the PS-side interpretation step).
         Placeholder words are dropped, like the paper's post-processing.
         """
-        arr = np.asarray(jax.device_get(self.data), dtype=np.float64)
+        _STATS["streams"] += 1
+        arr = _read(self.data)
         out: Dict[str, np.ndarray] = {}
         cursor = 0
         for label in self.schema:
@@ -282,7 +305,8 @@ class ProfileStream:
         truncated transfer are reported missing, sequence-number gaps are
         flagged, and every intact signal is returned as usual.
         """
-        arr = np.asarray(jax.device_get(self.data), dtype=np.float64)
+        _STATS["streams"] += 1
+        arr = _read(self.data)
         n = arr.shape[0]
         out: Dict[str, np.ndarray] = {}
         status: Dict[str, str] = {}
@@ -328,14 +352,12 @@ class ProfileStream:
                 name, payload = pending
                 pending = None
                 if label.size >= 3:  # crc32 guard: [seq, lo16, hi16]
-                    expect = np.asarray(jax.device_get(
-                        word_crc32(payload).astype(self.dtype)),
-                        dtype=np.float64)
+                    expect = _read(word_crc32(payload).astype(self.dtype))
                     ok = (float(words[1]) == float(expect[0])
                           and float(words[2]) == float(expect[1]))
                 else:                # xor24 guard: [seq, fold]
-                    expect = float(np.asarray(jax.device_get(
-                        word_checksum(payload).astype(self.dtype))))
+                    expect = float(_read(
+                        word_checksum(payload).astype(self.dtype)))
                     ok = float(words[1]) == expect
                 commit(name, payload, ok=ok)
                 seq = float(words[0])

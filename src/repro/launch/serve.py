@@ -35,7 +35,6 @@ class ServeResult:
     tokens: jnp.ndarray
     collector: ProfileCollector
     supervisor: ProfilingSupervisor
-    watchdog: Watchdog
     toks_per_s: float
     compile_s: float      # ahead-of-time compile of the serve step
     step_s: float         # wall seconds per generated step after the first
@@ -108,9 +107,10 @@ def run_serve(
     # uniform; attention archs could use the fused prefill_fn instead)
     t0 = time.perf_counter()
     for pos in range(prompt_len - 1):
-        nxt, caches, rows = retry_with_backoff(
-            serve_step, params, caches, prompts[:, pos:pos + 1], pos,
-            policy=retry)
+        with jax.profiler.TraceAnnotation("serve.step"):
+            nxt, caches, rows = retry_with_backoff(
+                serve_step, params, caches, prompts[:, pos:pos + 1], pos,
+                policy=retry)
     generated = [prompts]
     tok = prompts[:, -1:]
     t_gen = None
@@ -120,26 +120,32 @@ def run_serve(
             # host-side ops; time the steady state from here
             jax.block_until_ready(tok)
             t_gen = time.perf_counter()
-        t_step = time.time()
-        tok, caches, rows = retry_with_backoff(
-            serve_step, params, caches, tok, pos, policy=retry)
+        t_step = time.perf_counter()
+        with jax.profiler.TraceAnnotation("serve.step"):
+            tok, caches, rows = retry_with_backoff(
+                serve_step, params, caches, tok, pos, policy=retry)
         generated.append(tok)  # the data path delivers regardless of faults
         if not supervisor.active:
             continue
-        t_prof = time.time()
-        s = _profile_step(supervisor.policy, pos, max_len)
-        if corrupt_every and step_i % corrupt_every == 0:
-            s = s.with_bitflip(0)  # in-band fault: payload word bit flip
-        _, report = collector.ingest_verified(s)
-        if not report.ok:
-            supervisor.record_integrity_failure(report.summary())
-            continue
-        dt_step = time.time() - t_step
-        if watchdog.observe(dt_step):
-            supervisor.record_overhead(
-                (time.time() - t_prof) / max(dt_step, 1e-9))
-        else:
-            supervisor.step_ok()
+        with jax.profiler.TraceAnnotation("serve.profile"):
+            t_prof = time.perf_counter()
+            with jax.profiler.TraceAnnotation("serve.profile.build"):
+                s = _profile_step(supervisor.policy, pos, max_len)
+                if corrupt_every and step_i % corrupt_every == 0:
+                    s = s.with_bitflip(0)  # in-band fault: payload bit flip
+            with jax.profiler.TraceAnnotation("serve.profile.verify"):
+                decoded, report = s.decode_verified()
+            with jax.profiler.TraceAnnotation("serve.profile.fold"):
+                collector.fold_verified(decoded, report)
+            if not report.ok:
+                supervisor.record_integrity_failure(report.summary())
+                continue
+            dt_step = time.perf_counter() - t_step
+            if watchdog.observe(dt_step):
+                supervisor.record_overhead(
+                    (time.perf_counter() - t_prof) / max(dt_step, 1e-9))
+            else:
+                supervisor.step_ok()
     jax.block_until_ready(tok)
     t_end = time.perf_counter()
 
@@ -153,7 +159,7 @@ def run_serve(
     out = jnp.concatenate(generated, axis=1)
     return ServeResult(
         tokens=out, collector=collector, supervisor=supervisor,
-        watchdog=watchdog, toks_per_s=batch * (max_len - 1) / (t_end - t0),
+        toks_per_s=batch * (max_len - 1) / (t_end - t0),
         compile_s=compile_s,
         step_s=(t_end - t_gen) / (gen - 1) if gen > 1 else float("nan"))
 

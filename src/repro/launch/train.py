@@ -44,6 +44,7 @@ class TrainResult:
     end_step: int
     collector: ProfileCollector
     supervisor: ProfilingSupervisor
+    step_end_s: List[float]     # perf_counter once each step's loss is read
 
 
 def run_train(
@@ -116,25 +117,29 @@ def run_train(
 
     def step_fn(state, batch):
         params, opt_state = state
-        t0 = time.time()
-        params, opt_state, metrics, rows = jit_step(params, opt_state, batch)
-        dt = time.time() - t0
+        t0 = time.perf_counter()
+        with jax.profiler.TraceAnnotation("train.step"):
+            params, opt_state, metrics, rows = jit_step(
+                params, opt_state, batch)
+        dt = time.perf_counter() - t0
         if supervisor.active and rows is not None and rows.size:
-            t_prof = time.time()
-            ingest_rows(rows)
-            if watchdog.observe(dt):
-                supervisor.record_overhead(
-                    (time.time() - t_prof) / max(dt, 1e-9))
+            with jax.profiler.TraceAnnotation("train.profile"):
+                t_prof = time.perf_counter()
+                ingest_rows(rows)
+                if watchdog.observe(dt):
+                    supervisor.record_overhead(
+                        (time.perf_counter() - t_prof) / max(dt, 1e-9))
         return (params, opt_state), metrics
 
     loop = FaultTolerantLoop(
         ckpt_dir, (params, opt_state), step_fn, ckpt_every=ckpt_every,
         shardings=(p_shard, o_shard), heartbeat=hb, preemption=guard)
 
-    losses, grad_norms = [], []
+    losses, grad_norms, step_end_s = [], [], []
 
     def on_metrics(s, m):
-        loss = float(m["loss"])
+        loss = float(m["loss"])   # waits for the step on the device
+        step_end_s.append(time.perf_counter())
         losses.append(loss)
         grad_norms.append(float(m["grad_norm"]))
         # persistent stragglers starve the profile drain: fold them into
@@ -163,7 +168,8 @@ def run_train(
           f"(SPRING host FIFO signal)")
     return TrainResult(losses=losses, grad_norms=grad_norms,
                        params=loop.state[0], end_step=end_step,
-                       collector=collector, supervisor=supervisor)
+                       collector=collector, supervisor=supervisor,
+                       step_end_s=step_end_s)
 
 
 def main(argv=None):

@@ -15,10 +15,11 @@ def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float = 1e-6) -> jnp.ndar
     tensor round-trips HBM (§Perf H5 — the f32-conversion chains were the
     largest single memory term in the remat backward).
     """
-    xf = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-    scale = jax.lax.rsqrt(var + eps).astype(x.dtype)
-    return x * scale * weight.astype(x.dtype)
+    with jax.named_scope("norm"):
+        xf = x.astype(jnp.float32)
+        var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+        scale = jax.lax.rsqrt(var + eps).astype(x.dtype)
+        return x * scale * weight.astype(x.dtype)
 
 
 def layer_norm(x, weight, bias, eps: float = 1e-5):

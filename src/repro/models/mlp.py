@@ -3,6 +3,7 @@ from __future__ import annotations
 
 from typing import Dict
 
+import jax
 import jax.numpy as jnp
 
 from .common import ACTIVATIONS
@@ -28,8 +29,9 @@ def mlp_specs(d_model: int, d_ff: int, dtype, stacked: int = 0,
 
 def mlp_apply(p: Dict[str, jnp.ndarray], x: jnp.ndarray, activation: str) -> jnp.ndarray:
     act = ACTIVATIONS[activation]
-    if "wg" in p:                      # gated (SwiGLU / GeGLU)
-        h = act(x @ p["wg"]) * (x @ p["wi"])
-    else:                              # plain 2-matrix MLP (GPT-BigCode)
-        h = act(x @ p["wi"])
-    return h @ p["wo"]
+    with jax.named_scope("mlp"):
+        if "wg" in p:                  # gated (SwiGLU / GeGLU)
+            h = act(x @ p["wg"]) * (x @ p["wi"])
+        else:                          # plain 2-matrix MLP (GPT-BigCode)
+            h = act(x @ p["wi"])
+        return h @ p["wo"]

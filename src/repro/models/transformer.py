@@ -139,6 +139,7 @@ def _attn_project(cfg, p, x):
     return q, k, v
 
 
+@jax.named_scope("attn")
 def attn_apply_train(cfg, p, x, positions):
     """Full-sequence causal self-attention. Returns (out, logit_max, (k, v))."""
     q, k, v = _attn_project(cfg, p, x)
@@ -155,6 +156,7 @@ def attn_apply_train(cfg, p, x, positions):
     return out @ p["wo"], lmax, (k, v)
 
 
+@jax.named_scope("attn")
 def attn_apply_decode(cfg, p, x, k_cache, v_cache, pos):
     """One-token attention against the cache; writes position ``pos``."""
     B = x.shape[0]
@@ -162,10 +164,11 @@ def attn_apply_decode(cfg, p, x, k_cache, v_cache, pos):
     q, k, v = _attn_project(cfg, p, x)
     q = apply_rotary(q, positions, cfg.rope_theta, cfg.rotary_fraction)
     k = apply_rotary(k, positions, cfg.rope_theta, cfg.rotary_fraction)
-    k_cache = jax.lax.dynamic_update_slice(k_cache, k.astype(k_cache.dtype),
-                                           (0, pos, 0, 0))
-    v_cache = jax.lax.dynamic_update_slice(v_cache, v.astype(v_cache.dtype),
-                                           (0, pos, 0, 0))
+    with jax.named_scope("kv_update"):
+        k_cache = jax.lax.dynamic_update_slice(
+            k_cache, k.astype(k_cache.dtype), (0, pos, 0, 0))
+        v_cache = jax.lax.dynamic_update_slice(
+            v_cache, v.astype(v_cache.dtype), (0, pos, 0, 0))
     out, lmax = decode_attention(q, k_cache, v_cache, pos + 1)
     return out.reshape(B, 1, -1) @ p["wo"], lmax, (k_cache, v_cache)
 
@@ -248,12 +251,17 @@ def _remat(fn, cfg):
 # --------------------------------------------------------------------------- #
 # forward / loss
 # --------------------------------------------------------------------------- #
+@jax.named_scope("embed")
+def _embed(cfg, params, tokens):
+    return params["embed"][tokens].astype(jnp.dtype(cfg.activation_dtype))
+
+
 def lm_hidden(cfg, params, tokens, positions):
     """Token ids -> final hidden states.  Returns (h, rows, aux)."""
     spec = tape_spec_for(cfg)
     pdtype = jnp.dtype(cfg.profile_dtype)
     policy = validate_policy(cfg.profile_policy)
-    x = params["embed"][tokens].astype(jnp.dtype(cfg.activation_dtype))
+    x = _embed(cfg, params, tokens)
     x = shard_act(x, "batch", "seq", None)
 
     if cfg.scan_layers:
@@ -283,6 +291,7 @@ def lm_hidden(cfg, params, tokens, positions):
     return x, rows, aux
 
 
+@jax.named_scope("logits")
 def lm_logits(cfg, params, h):
     head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
     return h @ head
@@ -365,7 +374,7 @@ def lm_decode_step(cfg, params, caches, tokens, pos):
     spec = tape_spec_for(cfg)
     pdtype = jnp.dtype(cfg.profile_dtype)
     policy = validate_policy(cfg.profile_policy)
-    x = params["embed"][tokens].astype(jnp.dtype(cfg.activation_dtype))
+    x = _embed(cfg, params, tokens)
 
     def body(carry, per_layer):
         xc = carry
@@ -391,7 +400,7 @@ def lm_prefill(cfg, params, tokens):
     """Prefill: returns (last-position logits, caches filled to S)."""
     B, S = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
-    x = params["embed"][tokens].astype(jnp.dtype(cfg.activation_dtype))
+    x = _embed(cfg, params, tokens)
 
     def body(carry, p_l):
         xc = carry

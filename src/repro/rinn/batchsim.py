@@ -34,7 +34,10 @@ under ``vmap`` comes from JAX's ``while_loop`` batching rule (finished
 lanes freeze), so batched results are bit-identical to sequential runs.
 
 ``compile_stats()`` exposes trace/launch counters so tests and the
-``perf_stream`` benchmark can assert cache behaviour.
+``perf_stream`` benchmark can assert cache behaviour.  Every launch also
+writes two host spans for ``jax.profiler``: ``sim.pack`` (packing the
+machine and its lanes, up to the jitted call) and ``sim.unpack`` (the
+host outputs into ``SimResult``\\ s).
 """
 from __future__ import annotations
 
@@ -448,15 +451,18 @@ def run_sim_single(
     capacity_overrides: Optional[Dict[Edge, int]] = None,
 ) -> SimResult:
     """One run through the cached executable (the engine behind ``run_sim``)."""
-    plan = faults or FaultPlan()
-    bucket = machine_bucket(sim, _stall_slots(plan))
-    machine = _to_device(pack_machine(sim, bucket))
-    ops, cap_np, idle_limit = pack_faults(
-        sim, bucket, plan, capacity_overrides, profiled, max_cycles)
+    with jax.profiler.TraceAnnotation("sim.pack"):
+        plan = faults or FaultPlan()
+        bucket = machine_bucket(sim, _stall_slots(plan))
+        machine = _to_device(pack_machine(sim, bucket))
+        ops, cap_np, idle_limit = pack_faults(
+            sim, bucket, plan, capacity_overrides, profiled, max_cycles)
+        ops = _to_device(ops)
     _STATS["launches"] += 1
     _STATS["lanes"] += 1
-    outs = [np.asarray(o) for o in _jit_single(machine, _to_device(ops))]
-    return _unpack(sim, cap_np, faults, profiled, idle_limit, outs)
+    outs = [np.asarray(o) for o in _jit_single(machine, ops)]
+    with jax.profiler.TraceAnnotation("sim.unpack"):
+        return _unpack(sim, cap_np, faults, profiled, idle_limit, outs)
 
 
 def _broadcast(value, n: int, name: str) -> list:
@@ -498,20 +504,22 @@ def run_sim_batch(
                                faults=plans_l[0],
                                capacity_overrides=caps_l[0])]
 
-    stall_slots = max(_stall_slots(p or FaultPlan()) for p in plans_l)
-    bucket = machine_bucket(sim, stall_slots)
-    machine = _to_device(pack_machine(sim, bucket))
-    packed = [pack_faults(sim, bucket, p or FaultPlan(), c, pr, mc)
-              for p, c, pr, mc in zip(plans_l, caps_l, prof_l, mc_l)]
-    stacked = _stack([ops for ops, _, _ in packed])
+    with jax.profiler.TraceAnnotation("sim.pack"):
+        stall_slots = max(_stall_slots(p or FaultPlan()) for p in plans_l)
+        bucket = machine_bucket(sim, stall_slots)
+        machine = _to_device(pack_machine(sim, bucket))
+        packed = [pack_faults(sim, bucket, p or FaultPlan(), c, pr, mc)
+                  for p, c, pr, mc in zip(plans_l, caps_l, prof_l, mc_l)]
+        stacked = _stack([ops for ops, _, _ in packed])
     _STATS["launches"] += 1
     _STATS["lanes"] += n
     outs = [np.asarray(o) for o in _jit_lanes(machine, stacked)]
-    return [
-        _unpack(sim, packed[b][1], plans_l[b], prof_l[b], packed[b][2],
-                [o[b] for o in outs])
-        for b in range(n)
-    ]
+    with jax.profiler.TraceAnnotation("sim.unpack"):
+        return [
+            _unpack(sim, packed[b][1], plans_l[b], prof_l[b], packed[b][2],
+                    [o[b] for o in outs])
+            for b in range(n)
+        ]
 
 
 class TraceBuffers(NamedTuple):
@@ -566,19 +574,21 @@ def run_sim_traced(
     distinct value — keep it at the default unless you need finer time
     resolution); ``stride`` defaults to ``ceil(max_cycles / windows)``.
     """
-    plan = faults or FaultPlan()
-    bucket = machine_bucket(sim, _stall_slots(plan))
-    machine = _to_device(pack_machine(sim, bucket))
-    ops, cap_np, idle_limit = pack_faults(
-        sim, bucket, plan, capacity_overrides, profiled, max_cycles)
+    with jax.profiler.TraceAnnotation("sim.pack"):
+        plan = faults or FaultPlan()
+        bucket = machine_bucket(sim, _stall_slots(plan))
+        machine = _to_device(pack_machine(sim, bucket))
+        ops, cap_np, idle_limit = pack_faults(
+            sim, bucket, plan, capacity_overrides, profiled, max_cycles)
+        ops = _to_device(ops)
     stride = _trace_stride(stride, max_cycles, windows)
     jit_one, _ = _traced_jits(windows)
     _STATS["launches"] += 1
     _STATS["lanes"] += 1
-    outs = [np.asarray(o) for o in
-            jit_one(machine, _to_device(ops), jnp.int32(stride))]
-    res = _unpack(sim, cap_np, faults, profiled, idle_limit, outs[:7])
-    return res, _trim_trace(sim, stride, res.cycles, outs[7:])
+    outs = [np.asarray(o) for o in jit_one(machine, ops, jnp.int32(stride))]
+    with jax.profiler.TraceAnnotation("sim.unpack"):
+        res = _unpack(sim, cap_np, faults, profiled, idle_limit, outs[:7])
+        return res, _trim_trace(sim, stride, res.cycles, outs[7:])
 
 
 def run_sim_traced_batch(
@@ -610,22 +620,25 @@ def run_sim_traced_batch(
             faults=plans_l[0], capacity_overrides=caps_l[0],
             windows=windows, stride=stride)]
 
-    stall_slots = max(_stall_slots(p or FaultPlan()) for p in plans_l)
-    bucket = machine_bucket(sim, stall_slots)
-    machine = _to_device(pack_machine(sim, bucket))
-    packed = [pack_faults(sim, bucket, p or FaultPlan(), c, pr, max_cycles)
-              for p, c, pr in zip(plans_l, caps_l, prof_l)]
-    stacked = _stack([ops for ops, _, _ in packed])
+    with jax.profiler.TraceAnnotation("sim.pack"):
+        stall_slots = max(_stall_slots(p or FaultPlan()) for p in plans_l)
+        bucket = machine_bucket(sim, stall_slots)
+        machine = _to_device(pack_machine(sim, bucket))
+        packed = [pack_faults(sim, bucket, p or FaultPlan(), c, pr,
+                              max_cycles)
+                  for p, c, pr in zip(plans_l, caps_l, prof_l)]
+        stacked = _stack([ops for ops, _, _ in packed])
     _, jit_b = _traced_jits(windows)
     _STATS["launches"] += 1
     _STATS["lanes"] += n
     outs = [np.asarray(o) for o in jit_b(machine, stacked, jnp.int32(stride))]
     results = []
-    for b in range(n):
-        res = _unpack(sim, packed[b][1], plans_l[b], prof_l[b], packed[b][2],
-                      [o[b] for o in outs[:7]])
-        results.append((res, _trim_trace(sim, stride, res.cycles,
-                                         [o[b] for o in outs[7:]])))
+    with jax.profiler.TraceAnnotation("sim.unpack"):
+        for b in range(n):
+            res = _unpack(sim, packed[b][1], plans_l[b], prof_l[b],
+                          packed[b][2], [o[b] for o in outs[:7]])
+            results.append((res, _trim_trace(sim, stride, res.cycles,
+                                             [o[b] for o in outs[7:]])))
     return results
 
 
@@ -664,15 +677,18 @@ def run_sim_many(
                 sims[i], profiled=prof_l[i], max_cycles=mc_l[i],
                 faults=plans_l[i], capacity_overrides=caps_l[i])
             continue
-        machines = _stack([pack_machine(sims[i], bucket) for i in idxs])
-        packed = [pack_faults(sims[i], bucket, plans_l[i] or FaultPlan(),
-                              caps_l[i], prof_l[i], mc_l[i]) for i in idxs]
-        stacked = _stack([ops for ops, _, _ in packed])
+        with jax.profiler.TraceAnnotation("sim.pack"):
+            machines = _stack([pack_machine(sims[i], bucket) for i in idxs])
+            packed = [pack_faults(sims[i], bucket, plans_l[i] or FaultPlan(),
+                                  caps_l[i], prof_l[i], mc_l[i])
+                      for i in idxs]
+            stacked = _stack([ops for ops, _, _ in packed])
         _STATS["launches"] += 1
         _STATS["lanes"] += len(idxs)
         outs = [np.asarray(o) for o in _jit_machines(machines, stacked)]
-        for b, i in enumerate(idxs):
-            results[i] = _unpack(
-                sims[i], packed[b][1], plans_l[i], prof_l[i], packed[b][2],
-                [o[b] for o in outs])
+        with jax.profiler.TraceAnnotation("sim.unpack"):
+            for b, i in enumerate(idxs):
+                results[i] = _unpack(
+                    sims[i], packed[b][1], plans_l[i], prof_l[i],
+                    packed[b][2], [o[b] for o in outs])
     return results  # type: ignore[return-value]

@@ -95,11 +95,12 @@ def make_serve_step(cfg):
     """One-token decode step: (params, caches, tokens, pos) -> ..."""
     def serve_step(params, caches, tokens, pos):
         logits, new_caches, rows = decode_fn(cfg, params, caches, tokens, pos)
-        # mask vocab-padding slots (embed table is padded for sharding)
-        pad_mask = jnp.where(jnp.arange(logits.shape[-1]) >= cfg.vocab_size,
-                             -1e30, 0.0)
-        next_tok = jnp.argmax(logits[:, -1, :] + pad_mask, axis=-1)[:, None]
-        next_tok = next_tok.astype(tokens.dtype)
-        return next_tok, new_caches, rows
+        with jax.named_scope("logits"):
+            # mask vocab-padding slots (embed table is padded for sharding)
+            pad_mask = jnp.where(
+                jnp.arange(logits.shape[-1]) >= cfg.vocab_size, -1e30, 0.0)
+            next_tok = jnp.argmax(logits[:, -1, :] + pad_mask,
+                                  axis=-1)[:, None]
+        return next_tok.astype(tokens.dtype), new_caches, rows
 
     return serve_step
