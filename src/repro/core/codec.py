@@ -137,7 +137,10 @@ _CRC32_TABLE = None
 
 
 def _crc32_table() -> jnp.ndarray:
-    """The 256-entry byte-at-a-time CRC-32 table (built once, host-side)."""
+    """The 256-entry byte-at-a-time CRC-32 table (built once, host-side).
+
+    The cache holds the NumPy table: a ``jnp`` array made while a jitted
+    caller traces is that trace's tracer, and would leak into the next."""
     global _CRC32_TABLE
     if _CRC32_TABLE is None:
         import numpy as np
@@ -145,8 +148,8 @@ def _crc32_table() -> jnp.ndarray:
         t = np.arange(256, dtype=np.uint32)
         for _ in range(8):
             t = np.where(t & 1, (t >> 1) ^ np.uint32(_CRC32_POLY), t >> 1)
-        _CRC32_TABLE = jnp.asarray(t)
-    return _CRC32_TABLE
+        _CRC32_TABLE = t
+    return jnp.asarray(_CRC32_TABLE)
 
 
 def word_crc32(values: jnp.ndarray) -> jnp.ndarray:

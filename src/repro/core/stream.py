@@ -37,6 +37,7 @@ Two collection policies mirror the paper:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -79,6 +80,19 @@ def stream_stats() -> Dict[str, int]:
 def reset_stream_stats() -> None:
     for k in _STATS:
         _STATS[k] = 0
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _guard_words(payload, algo: str, dtype) -> jnp.ndarray:
+    """The checksum words of a guard over ``payload``: ``[fold]`` for
+    ``xor24``, ``[lo16, hi16]`` for ``crc32``, in the stream's ``dtype``.
+    One compiled program per algorithm, payload size and dtype, shared by
+    ``append_guarded`` and the verified decoder's recompute."""
+    if algo == "crc32":
+        check = word_crc32(payload)
+    else:
+        check = word_checksum(payload)[None]
+    return check.astype(dtype)
 
 
 def _read(x) -> np.ndarray:
@@ -212,10 +226,7 @@ class ProfileStream:
         out = self.append(name, metric, values)
         payload = out.data[self.n_words:]
         seq = jnp.full((1,), float(self._next_seq()), dtype=self.dtype)
-        if algo == "crc32":
-            check = word_crc32(payload).astype(self.dtype)
-        else:
-            check = word_checksum(payload).astype(self.dtype)[None]
+        check = _guard_words(payload, algo, self.dtype)
         guard = Label(name=f"{name}/__guard__", metric=INTEGRITY_METRIC,
                       size=1 + int(check.shape[0]))
         return ProfileStream(
@@ -351,15 +362,11 @@ class ProfileStream:
                     continue
                 name, payload = pending
                 pending = None
-                if label.size >= 3:  # crc32 guard: [seq, lo16, hi16]
-                    expect = _read(word_crc32(payload).astype(self.dtype))
-                    ok = (float(words[1]) == float(expect[0])
-                          and float(words[2]) == float(expect[1]))
-                else:                # xor24 guard: [seq, fold]
-                    expect = float(_read(
-                        word_checksum(payload).astype(self.dtype)))
-                    ok = float(words[1]) == expect
-                commit(name, payload, ok=ok)
+                # [seq, lo16, hi16] is a crc32 guard, [seq, fold] an xor24
+                algo = "crc32" if label.size >= 3 else "xor24"
+                expect = _read(_guard_words(payload, algo, self.dtype))
+                ok = np.array_equal(words[1:1 + expect.shape[0]], expect)
+                commit(name, payload, ok=bool(ok))
                 seq = float(words[0])
                 if np.isfinite(seq) and 0 <= seq < 2**31:
                     seen_seq.append(int(seq))

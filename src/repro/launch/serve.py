@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import time
 
 import jax
@@ -40,22 +41,25 @@ class ServeResult:
     step_s: float         # wall seconds per generated step after the first
 
 
-def _profile_step(policy: str, pos: int, max_len: int) -> ProfileStream:
+@functools.partial(jax.jit, static_argnums=0)
+def _profile_step(policy: str, pos, max_len) -> ProfileStream:
     """Build this step's profile stream at the supervisor's fidelity rung.
 
     ``inline`` guards every signal record individually (the faithful
     mechanism); ``shortcut`` emits one fixed-width guarded record (the
-    tape-style O(L) path — cheaper, coarser framing).
+    tape-style O(L) path — cheaper, coarser framing).  One compiled program
+    per policy: ``pos`` and ``max_len`` are traced, so every step and cache
+    length reuses it.
     """
-    occ = M.kv_occupancy(jnp.full((1,), pos + 1), max_len)
+    used = jnp.full((1,), pos + 1)
+    occ = M.kv_occupancy(used, max_len)
+    position = used.astype(jnp.float32)
     s = ProfileStream.create()
     if policy == "inline":
         s = s.append_guarded("kv/occupancy", "fifo_fullness", occ)
-        s = s.append_guarded("kv/position", "position",
-                             jnp.full((1,), float(pos + 1)))
+        s = s.append_guarded("kv/position", "position", position)
     else:  # shortcut: one guarded record row
-        row = jnp.concatenate([jnp.atleast_1d(occ),
-                               jnp.full((1,), float(pos + 1))])
+        row = jnp.concatenate([jnp.atleast_1d(occ), position])
         s = s.append_guarded("kv/record", "record_row", row)
     return s
 
