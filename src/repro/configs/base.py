@@ -41,6 +41,23 @@ class ModelConfig:
     n_shared_experts: int = 0
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
+    # "softmax": capacity-buffered top-k (moe.moe_apply).  "sigmoid":
+    # DeepSeek-V3 noaux_tc routing, dropless, over a held share of the
+    # experts (moe.expert_share_apply)
+    router: str = "softmax"
+    routed_scaling: float = 1.0
+    expert_shards: int = 1                  # experts split in equal shares
+    expert_shard: int = 0                   # the share this model holds
+
+    # --- latent attention (MLA, DeepSeek-V2/V3): on when kv_lora_rank > 0 ---
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+
+    # --- leading dense layers before the MoE stack (first_k_dense_replace) ---
+    first_k_dense: int = 0
+    dense_d_ff: int = 0
 
     # --- SSM (mamba2 / SSD) ---
     ssm_state: int = 0
@@ -84,11 +101,43 @@ class ModelConfig:
             raise ValueError(f"family must be one of {FAMILIES}")
         if self.family in ("moe",) and (self.n_experts <= 0 or self.top_k <= 0):
             raise ValueError("moe family needs n_experts and top_k")
+        if self.router not in ("softmax", "sigmoid"):
+            raise ValueError("router must be softmax or sigmoid")
+        if self.expert_shards > 1 and self.router != "sigmoid":
+            raise ValueError("only the sigmoid router holds a share of experts")
+        if self.n_experts % self.expert_shards or not (
+                0 <= self.expert_shard < self.expert_shards):
+            raise ValueError("expert shares must split n_experts evenly")
+        if bool(self.first_k_dense) != self.mla:
+            raise ValueError("MLA is built with leading dense layers "
+                             "(the DeepSeek-V3 layout), and only with them")
 
     # ------------------------------------------------------------------ #
     @property
     def head_dim(self) -> int:
         return self.d_head if self.d_head else self.d_model // self.n_heads
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_dim(self) -> int:
+        """Cached values per position and layer under MLA: the normalised
+        latent and the rotated shared rope key."""
+        return self.kv_lora_rank + self.qk_rope_dim
+
+    @property
+    def held_experts(self) -> Tuple[int, int]:
+        """[lo, hi) of the routed experts this model holds."""
+        n = self.n_experts // self.expert_shards
+        return self.expert_shard * n, (self.expert_shard + 1) * n
+
+    def with_expert_share(self, shard: int, shards: int) -> "ModelConfig":
+        """This model holding share ``shard`` of ``shards`` of the experts:
+        one chip's part of an expert-parallel deployment."""
+        return dataclasses.replace(self, expert_shard=shard,
+                                   expert_shards=shards)
 
     @property
     def padded_vocab(self) -> int:
@@ -126,19 +175,27 @@ class ModelConfig:
         dh, H, KV = self.head_dim, self.n_heads, self.n_kv_heads
         embed = v * d * (1 if self.tie_embeddings else 2)
         attn = d * H * dh + 2 * d * KV * dh + H * dh * d
+        if self.mla:
+            r, dn, dr, dv = (self.kv_lora_rank, self.qk_nope_dim,
+                             self.qk_rope_dim, self.v_head_dim)
+            attn = (d * H * (dn + dr) + d * (r + dr) + r * H * (dn + dv)
+                    + H * dv * d)
         if self.family == "ssm":
             per_layer = self._mamba_params()
             return embed + L * per_layer
         mlp3 = (3 if self.mlp_gated else 2) * d * f
         if self.family == "moe":
-            ff_all = self.n_experts * mlp3 + d * self.n_experts
+            lo, hi = self.held_experts
+            ff_all = (hi - lo) * mlp3 + d * self.n_experts
             ff_act = self.top_k * mlp3 + d * self.n_experts
             if self.n_shared_experts:
                 shared = self.n_shared_experts * mlp3
                 ff_all += shared
                 ff_act += shared
             per_layer = attn + (ff_act if active_only else ff_all)
-            return embed + L * per_layer
+            k = self.first_k_dense
+            dense = attn + 3 * d * self.dense_d_ff
+            return embed + (L - k) * per_layer + k * dense
         if self.family == "hybrid":
             mamba = self._mamba_params()
             n_attn = (L // self.shared_attn_every) if self.shared_attn_every else 0
@@ -177,6 +234,14 @@ class ModelConfig:
         )
         if self.n_experts:
             small.update(n_experts=4, top_k=2)
+        if self.router == "sigmoid":
+            # enough experts for eight shares of two, published top-6
+            small.update(n_experts=16, top_k=6)
+        if self.mla:
+            small.update(kv_lora_rank=32, qk_nope_dim=16, qk_rope_dim=8,
+                         v_head_dim=16)
+        if self.first_k_dense:
+            small.update(n_layers=self.first_k_dense + 2, dense_d_ff=192)
         if self.n_encoder_layers:
             small.update(n_encoder_layers=2)
         if self.shared_attn_every:

@@ -13,6 +13,7 @@ ARCH_IDS = [
     "mistral-large-123b",
     "qwen2.5-14b",
     "moonshot-v1-16b-a3b",
+    "moonlight-16b-a3b",
     "qwen3-moe-235b-a22b",
     "mamba2-780m",
     "zamba2-1.2b",
@@ -26,6 +27,7 @@ _MODULES = {
     "mistral-large-123b": "mistral_large_123b",
     "qwen2.5-14b": "qwen2_5_14b",
     "moonshot-v1-16b-a3b": "moonshot_v1_16b_a3b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
     "qwen3-moe-235b-a22b": "qwen3_moe_235b_a22b",
     "mamba2-780m": "mamba2_780m",
     "zamba2-1.2b": "zamba2_1_2b",

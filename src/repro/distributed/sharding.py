@@ -107,7 +107,10 @@ def cache_axes(cfg):
             conv_bc=(None, "batch", None, None),
             state=(None, "batch", "heads", None, None),
         )
-    from ..models.transformer import KvCaches
+    from ..models.transformer import KvCaches, LatentCaches
+    if cfg.mla:
+        lat = (None, "batch", None, None)
+        return LatentCaches(prefix=lat, blocks=lat)
     return KvCaches(k=kv5, v=kv5)
 
 

@@ -21,7 +21,7 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro.configs import ARCH_IDS, get_config
+from repro.configs import ARCH_IDS, ModelConfig, get_config
 from repro.core import ProfileCollector, ProfileStream, metrics as M
 from repro.distributed.fault import (
     ProfilingSupervisor, RetryPolicy, Watchdog, retry_with_backoff,
@@ -65,7 +65,8 @@ def _profile_step(policy: str, pos, max_len) -> ProfileStream:
 
 
 def run_serve(
-    arch: str = "chatglm3-6b", *, reduced: bool = False, batch: int = 4,
+    arch: str | ModelConfig = "chatglm3-6b", *, reduced: bool = False,
+    batch: int = 4,
     prompt_len: int = 16, gen: int = 16, seed: int = 0,
     profile_policy: str = "inline", failure_threshold: int = 2,
     overhead_budget: float = 0.25, step_budget_s: float = 5.0,
@@ -77,9 +78,10 @@ def run_serve(
     stream (fault-injection hook): the verified decode quarantines the
     damaged record, the supervisor counts the strike, and after
     ``failure_threshold`` consecutive strikes profiling steps down a rung —
-    tokens keep flowing throughout.
+    tokens keep flowing throughout.  ``arch`` is a registry id or a
+    configuration (e.g. one chip's expert share, ``with_expert_share``).
     """
-    cfg = get_config(arch)
+    cfg = get_config(arch) if isinstance(arch, str) else arch
     if reduced:
         cfg = cfg.reduced()
 

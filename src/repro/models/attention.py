@@ -15,7 +15,9 @@ All paths return ``(output, logit_max)`` — the max attention logit is the
 in-band profiling tap (overflow sentinel), SPRING-style.
 
 GQA is computed in grouped form [B, T, KV, G, Dh] without materializing
-repeated KV heads.
+repeated KV heads.  Values may be narrower than queries and keys (latent
+attention's plain path: q/k 192 wide, v 128): outputs and accumulators take
+v's width, the softmax scale q's.
 """
 from __future__ import annotations
 
@@ -57,7 +59,7 @@ def naive_attention(
     lmax = jnp.max(logits)
     w = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     out = jnp.einsum("bkgts,bskd->btkgd", w, v)
-    return out.reshape(b, t, h, dh), lmax
+    return out.reshape(b, t, h, v.shape[-1]), lmax
 
 
 def _online_update(m, l, acc, logits, v_chunk):
@@ -95,7 +97,7 @@ def flash_tri_attention(
         n_k = math.ceil(kv_hi / kc)
         m = jnp.full((b, kv, h // kv, q_len), NEG_INF, jnp.float32)
         l = jnp.zeros((b, kv, h // kv, q_len), jnp.float32)
-        acc = jnp.zeros((b, kv, h // kv, q_len, dh), jnp.float32)
+        acc = jnp.zeros((b, kv, h // kv, q_len, v.shape[-1]), jnp.float32)
         for j in range(n_k):
             k0 = j * kc
             k_len = min(kc, kv_hi - k0)
@@ -107,7 +109,7 @@ def flash_tri_attention(
                 logits = logits + jnp.where(kv_pos <= q_pos, 0.0, NEG_INF)
             m, l, acc = _online_update(m, l, acc, logits, v[:, k0:k0 + k_len])
         out_i = (acc / l[..., None]).astype(q.dtype)   # [b, kv, g, q_len, dh]
-        outs.append(out_i.transpose(0, 3, 1, 2, 4).reshape(b, q_len, h, dh))
+        outs.append(out_i.transpose(0, 3, 1, 2, 4).reshape(b, q_len, h, -1))
         lmaxes.append(jnp.max(m))
     return jnp.concatenate(outs, axis=1), jnp.max(jnp.stack(lmaxes))
 
@@ -117,7 +119,7 @@ def flash_scan_attention(
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Online-softmax attention scanning KV chunks (compact HLO, long S)."""
     b, t, h, dh = q.shape
-    s, n_kv = k.shape[1], k.shape[2]
+    s, n_kv, dv = k.shape[1], k.shape[2], v.shape[-1]
     kc = min(kv_chunk, s)
     if s % kc:  # pad KV to a chunk multiple; padded positions are masked out
         pad = kc - s % kc
@@ -128,7 +130,7 @@ def flash_scan_attention(
     qg = _group(q, n_kv)
     scale = 1.0 / math.sqrt(dh)
     kr = k.reshape(b, n_chunks, kc, n_kv, dh).transpose(1, 0, 2, 3, 4)
-    vr = v.reshape(b, n_chunks, kc, n_kv, dh).transpose(1, 0, 2, 3, 4)
+    vr = v.reshape(b, n_chunks, kc, n_kv, dv).transpose(1, 0, 2, 3, 4)
     del k, v
 
     def body(carry, chunk):
@@ -148,12 +150,12 @@ def flash_scan_attention(
     init = (
         jnp.full((b, n_kv, g, t), NEG_INF, jnp.float32),
         jnp.zeros((b, n_kv, g, t), jnp.float32),
-        jnp.zeros((b, n_kv, g, t, dh), jnp.float32),
+        jnp.zeros((b, n_kv, g, t, dv), jnp.float32),
         jnp.int32(0),
     )
     (m, l, acc, _), _ = jax.lax.scan(body, init, (kr, vr))
     out = (acc / l[..., None]).astype(q.dtype)
-    return out.transpose(0, 3, 1, 2, 4).reshape(b, t, h, dh), jnp.max(m)
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, t, h, dv), jnp.max(m)
 
 
 def decode_attention(

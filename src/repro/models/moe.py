@@ -13,6 +13,10 @@ routing never crosses shards: argsort the (S·k) expert assignments of each
 row, rank entries within their expert run, keep ranks below capacity, and
 gather/scatter through an [E, C] buffer.  Experts shard over the ``expert``
 logical axis (EP on the mesh's model axis).
+
+``expert_share_apply`` is the DeepSeek-V3 layer (sigmoid scores, a
+selection-only correction bias, dropless) holding one share of the experts,
+as one chip of an expert-parallel deployment does.
 """
 from __future__ import annotations
 
@@ -27,19 +31,31 @@ from .params import ParamSpec
 from ..distributed.ctx import shard_act
 
 
-def moe_specs(d_model: int, d_ff: int, n_experts: int, dtype,
-              stacked: int = 0, n_shared: int = 0) -> Dict[str, ParamSpec]:
-    def spec(shape, axes):
-        if stacked:
-            return ParamSpec((stacked,) + shape, dtype, ("layers",) + axes)
-        return ParamSpec(shape, dtype, axes)
+# std of the seeded correction bias: small against the spread of sigmoid
+# scores, so it moves some selections and never the weights
+ROUTER_BIAS_STD = 0.05
 
+
+def moe_specs(d_model: int, d_ff: int, n_experts: int, dtype,
+              stacked: int = 0, n_shared: int = 0, n_held: int = 0,
+              router_bias: bool = False) -> Dict[str, ParamSpec]:
+    """Router over all ``n_experts``; expert weights for the ``n_held`` held
+    here (all by default); a float32 correction bias for sigmoid routing."""
+    def spec(shape, axes, dt=dtype, **kw):
+        if stacked:
+            return ParamSpec((stacked,) + shape, dt, ("layers",) + axes, **kw)
+        return ParamSpec(shape, dt, axes, **kw)
+
+    n_held = n_held or n_experts
     specs = {
         "router": spec((d_model, n_experts), ("embed", None)),
-        "w1": spec((n_experts, d_model, d_ff), ("expert", "embed", None)),
-        "wg": spec((n_experts, d_model, d_ff), ("expert", "embed", None)),
-        "w2": spec((n_experts, d_ff, d_model), ("expert", None, "embed")),
+        "w1": spec((n_held, d_model, d_ff), ("expert", "embed", None)),
+        "wg": spec((n_held, d_model, d_ff), ("expert", "embed", None)),
+        "w2": spec((n_held, d_ff, d_model), ("expert", None, "embed")),
     }
+    if router_bias:
+        specs["router_bias"] = spec((n_experts,), (None,), jnp.float32,
+                                    scale=ROUTER_BIAS_STD)
     if n_shared:
         specs.update({
             "shared_wi": spec((d_model, n_shared * d_ff), ("embed", "mlp")),
@@ -122,7 +138,7 @@ def moe_apply(
     # stay sharded on the expert axis, SPMD lowers this to local partial
     # sums + ONE [B, S, d] all-reduce — versus the gather-based combine,
     # which all-reduces the f32 [B, S·k, d] gathered tensor (top_k· and
-    # fp32-fold larger).  See EXPERIMENTS.md §Perf hillclimb H1.
+    # fp32-fold larger).
     wbuf = jax.vmap(
         lambda e_, s_, w_: jnp.zeros((E, C + 1), topk_w.dtype)
         .at[e_, s_].set(w_))(sorted_e, dest_slot, w_sorted)
@@ -151,4 +167,75 @@ def moe_apply(
         hs = act(x @ p["shared_wg"]) * (x @ p["shared_wi"])
         y = y + hs @ p["shared_wo"]
 
+    return y, aux, profile
+
+
+def sigmoid_route(p, x, top_k: int, scaling: float):
+    """DeepSeek-V3 routing (noaux_tc, one group) in float32.
+
+    Returns (scores [.., E], selected experts [.., k], their weights [.., k]):
+    the top-k of the sigmoid scores plus the correction bias are selected;
+    the weights are the selected scores without the bias, renormalised and
+    times ``scaling``.
+    """
+    logits = jnp.matmul(x.astype(jnp.float32), p["router"].astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(scores + p["router_bias"], top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    return scores, idx, w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * scaling
+
+
+def expert_share_apply(
+    p: Dict[str, jnp.ndarray],
+    x: jnp.ndarray,                  # [B, S, d]
+    *,
+    top_k: int,
+    scaling: float,
+    held: Tuple[int, int],
+    activation: str,
+) -> Tuple[jnp.ndarray, jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """DeepSeek-V3 MoE layer holding experts ``[lo, hi)`` of the router's E.
+
+    Routes over all E (``sigmoid_route``) and returns its held experts'
+    part of the routed sum plus the shared experts, whole: what one chip of
+    an expert-parallel deployment computes before the exchange.
+    No token is dropped: every held expert runs on every token and weights
+    it by its routing weight, zero where it was not selected.  At decode
+    batches this reads each held expert's weights once, as a grouped
+    product would.
+
+    Returns (y, aux, profile): the sequence-wise balance loss over all E
+    (DeepSeek-V3), and per held expert the tokens routed to it plus the
+    share of assignments routed to experts not held.
+    """
+    act = ACTIVATIONS[activation]
+    B, S, d = x.shape
+    E = p["router"].shape[-1]
+    lo, hi = held
+    with jax.named_scope("mlp"):
+        with jax.named_scope("router"):
+            scores, idx, w = sigmoid_route(p, x, top_k, scaling)
+            picked = idx[..., None] == jnp.arange(lo, hi)     # [B, S, k, Eh]
+            gate = jnp.sum(jnp.where(picked, w[..., None], 0.0), axis=-2)
+        with jax.named_scope("experts"):
+            xt = x.reshape(B * S, d)
+            h = act(jnp.einsum("td,edf->etf", xt, p["wg"])) * jnp.einsum(
+                "td,edf->etf", xt, p["w1"])
+            ye = jnp.einsum("etf,efd->etd", h, p["w2"])       # [Eh, T, d]
+            y = jnp.einsum("etd,te->td", ye, gate.reshape(B * S, hi - lo),
+                           preferred_element_type=jnp.float32)
+            y = y.astype(x.dtype).reshape(B, S, d)
+        with jax.named_scope("shared_experts"):
+            hs = act(x @ p["shared_wg"]) * (x @ p["shared_wi"])
+            y = y + hs @ p["shared_wo"]
+
+    counts = jnp.sum(jax.nn.one_hot(idx, E, dtype=jnp.float32), axis=(1, 2))
+    frac = counts * E / (top_k * S)                           # [B, E]
+    probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
+    aux = jnp.mean(jnp.sum(frac * jnp.mean(probs, axis=1), axis=-1))
+
+    tokens = jnp.sum(picked, axis=(0, 1, 2)).astype(jnp.float32)
+    unheld = 1.0 - jnp.sum(tokens) / (B * S * top_k)
+    profile = {"expert_tokens": tokens, "unheld_share": unheld[None]}
     return y, aux, profile

@@ -20,8 +20,9 @@ from ..core.stream import validate_policy
 from .attention import attention, decode_attention
 from ..distributed.ctx import shard_act
 from .common import apply_rotary, rms_norm
+from .mla import mla_apply_decode, mla_apply_train, mla_specs
 from .mlp import mlp_apply, mlp_specs
-from .moe import moe_apply, moe_specs
+from .moe import expert_share_apply, moe_apply, moe_specs
 from .params import ParamSpec
 from .ssm import (
     SsmCache, ssm_block_apply, ssm_block_decode, ssm_cache_init, ssm_specs,
@@ -55,7 +56,9 @@ def attn_specs(cfg, stacked: int = 0) -> Dict[str, ParamSpec]:
     return out
 
 
-def block_specs(cfg, stacked: int = 0) -> Dict[str, Any]:
+def block_specs(cfg, stacked: int = 0, dense: bool = False) -> Dict[str, Any]:
+    """One layer's weights; ``dense`` gives a leading dense layer of an MoE
+    model its MLP of width ``dense_d_ff``."""
     dtype = cfg.dtype()
 
     def nspec(**kw):
@@ -69,11 +72,15 @@ def block_specs(cfg, stacked: int = 0) -> Dict[str, Any]:
     out = {
         "norm1": nspec(),
         "norm2": nspec(),
-        "attn": attn_specs(cfg, stacked),
+        "attn": mla_specs(cfg, stacked) if cfg.mla else attn_specs(cfg, stacked),
     }
-    if cfg.family == "moe":
+    if dense:
+        out["mlp"] = mlp_specs(cfg.d_model, cfg.dense_d_ff, dtype, stacked)
+    elif cfg.family == "moe":
+        lo, hi = cfg.held_experts
         out["moe"] = moe_specs(cfg.d_model, cfg.d_ff, cfg.n_experts, dtype,
-                               stacked, cfg.n_shared_experts)
+                               stacked, cfg.n_shared_experts, n_held=hi - lo,
+                               router_bias=cfg.router == "sigmoid")
     else:
         out["mlp"] = mlp_specs(cfg.d_model, cfg.d_ff, dtype, stacked,
                                gated=cfg.mlp_gated)
@@ -92,10 +99,14 @@ def lm_specs(cfg) -> Dict[str, Any]:
     if not cfg.tie_embeddings:
         specs["lm_head"] = ParamSpec((cfg.d_model, cfg.padded_vocab), dtype,
                                      ("embed", "vocab"))
+    n = cfg.n_layers - cfg.first_k_dense
+    if cfg.first_k_dense:
+        specs["prefix"] = [block_specs(cfg, dense=True)
+                           for _ in range(cfg.first_k_dense)]
     if cfg.scan_layers:
-        specs["blocks"] = block_specs(cfg, stacked=cfg.n_layers)
+        specs["blocks"] = block_specs(cfg, stacked=n)
     else:
-        specs["blocks"] = [block_specs(cfg) for _ in range(cfg.n_layers)]
+        specs["blocks"] = [block_specs(cfg) for _ in range(n)]
     return specs
 
 
@@ -108,7 +119,13 @@ def tape_spec_for(cfg) -> TapeSpec:
         labels.append(Label("state_rms", "state_rms", 1))
     else:
         labels.append(Label("attn_logit_max", "logit_max", 1))
-    if cfg.family == "moe":
+    if cfg.family == "moe" and cfg.router == "sigmoid":
+        lo, hi = cfg.held_experts
+        labels += [
+            Label("expert_tokens", "fifo_fullness", hi - lo),
+            Label("unheld_share", "share", 1),
+        ]
+    elif cfg.family == "moe":
         labels += [
             Label("expert_fullness", "fifo_fullness", cfg.n_experts),
             Label("expert_overflow", "fifo_overflow", cfg.n_experts),
@@ -173,6 +190,26 @@ def attn_apply_decode(cfg, p, x, k_cache, v_cache, pos):
     return out.reshape(B, 1, -1) @ p["wo"], lmax, (k_cache, v_cache)
 
 
+def _ffn(cfg, p, x):
+    """The block's feed-forward slot: (out, aux_loss or None, tape)."""
+    if "moe" not in p:
+        return mlp_apply(p["mlp"], x, cfg.activation), None, {}
+    if cfg.router == "sigmoid":
+        return expert_share_apply(
+            p["moe"], x, top_k=cfg.top_k, scaling=cfg.routed_scaling,
+            held=cfg.held_experts, activation=cfg.activation)
+    return moe_apply(p["moe"], x, top_k=cfg.top_k,
+                     capacity_factor=cfg.capacity_factor,
+                     activation=cfg.activation)
+
+
+def _attn_train(cfg, p, x, positions):
+    """(out, logit_max, what the cache keeps: (k, v) or the MLA latent)."""
+    if cfg.mla:
+        return mla_apply_train(cfg, p, x, positions)
+    return attn_apply_train(cfg, p, x, positions)
+
+
 def block_apply_train(cfg, p, x, positions):
     """Pre-norm block. Returns (x, tape_values, aux_loss)."""
     aux = jnp.float32(0.0)
@@ -183,19 +220,14 @@ def block_apply_train(cfg, p, x, positions):
         x = x + h
         tape.update(prof)
     else:
-        h, lmax, _ = attn_apply_train(
+        h, lmax, _ = _attn_train(
             cfg, p["attn"], rms_norm(x, p["norm1"], cfg.norm_eps), positions)
         x = x + h
         tape["attn_logit_max"] = lmax[None]
-        h_in = rms_norm(x, p["norm2"], cfg.norm_eps)
-        if cfg.family == "moe":
-            h, moe_aux, prof = moe_apply(
-                p["moe"], h_in, top_k=cfg.top_k,
-                capacity_factor=cfg.capacity_factor, activation=cfg.activation)
+        h, moe_aux, prof = _ffn(cfg, p, rms_norm(x, p["norm2"], cfg.norm_eps))
+        if moe_aux is not None:
             aux = aux + cfg.router_aux_weight * moe_aux
-            tape.update(prof)
-        else:
-            h = mlp_apply(p["mlp"], h_in, cfg.activation)
+        tape.update(prof)
         x = x + h
     x = shard_act(x, "batch", "seq", None)
     xf = x.astype(jnp.float32)
@@ -205,7 +237,8 @@ def block_apply_train(cfg, p, x, positions):
 
 
 def block_apply_decode(cfg, p, x, cache, pos):
-    """cache: (k, v) tensors or SsmCache. Returns (x, cache, tape)."""
+    """cache: (k, v) tensors, the MLA latent cache or SsmCache.
+    Returns (x, cache, tape)."""
     tape: Dict[str, jnp.ndarray] = {}
     if cfg.family == "ssm":
         h, new_cache, prof = ssm_block_decode(
@@ -213,20 +246,17 @@ def block_apply_decode(cfg, p, x, cache, pos):
         x = x + h
         tape.update(prof)
     else:
-        k_cache, v_cache = cache
-        h, lmax, new_cache = attn_apply_decode(
-            cfg, p["attn"], rms_norm(x, p["norm1"], cfg.norm_eps),
-            k_cache, v_cache, pos)
+        x_in = rms_norm(x, p["norm1"], cfg.norm_eps)
+        if cfg.mla:
+            h, lmax, new_cache = mla_apply_decode(cfg, p["attn"], x_in,
+                                                  cache, pos)
+        else:
+            h, lmax, new_cache = attn_apply_decode(cfg, p["attn"], x_in,
+                                                   *cache, pos)
         x = x + h
         tape["attn_logit_max"] = lmax[None]
-        h_in = rms_norm(x, p["norm2"], cfg.norm_eps)
-        if cfg.family == "moe":
-            h, _, prof = moe_apply(
-                p["moe"], h_in, top_k=cfg.top_k,
-                capacity_factor=cfg.capacity_factor, activation=cfg.activation)
-            tape.update(prof)
-        else:
-            h = mlp_apply(p["mlp"], h_in, cfg.activation)
+        h, _, prof = _ffn(cfg, p, rms_norm(x, p["norm2"], cfg.norm_eps))
+        tape.update(prof)
         x = x + h
     xf = x.astype(jnp.float32)
     tape["act_rms"] = jnp.sqrt(jnp.mean(jnp.square(xf)) + 1e-30)[None]
@@ -263,6 +293,8 @@ def lm_hidden(cfg, params, tokens, positions):
     policy = validate_policy(cfg.profile_policy)
     x = _embed(cfg, params, tokens)
     x = shard_act(x, "batch", "seq", None)
+    x, pre_rows, aux_pre = _prefix_train(cfg, params, x, positions, spec,
+                                         pdtype, policy)
 
     if cfg.scan_layers:
         def body(carry, per_layer):
@@ -274,10 +306,9 @@ def lm_hidden(cfg, params, tokens, positions):
             return (xc, aux + aux_l), row
 
         body = _remat(body, cfg)
-        (x, aux), rows = jax.lax.scan(body, (x, jnp.float32(0.0)),
-                                      params["blocks"])
+        (x, aux), rows = jax.lax.scan(body, (x, aux_pre), params["blocks"])
     else:
-        aux = jnp.float32(0.0)
+        aux = aux_pre
         row_list = []
         for p_l in params["blocks"]:
             x, tape, aux_l = block_apply_train(cfg, p_l, x, positions)
@@ -285,10 +316,34 @@ def lm_hidden(cfg, params, tokens, positions):
             if policy != "off":
                 row_list.append(spec.emit(tape, pdtype))
         rows = (jnp.stack(row_list) if (row_list and policy != "off")
-                else jnp.zeros((cfg.n_layers, 0), pdtype))
+                else jnp.zeros((len(params["blocks"]), 0), pdtype))
 
+    if pre_rows is not None:
+        rows = jnp.concatenate([pre_rows, rows])
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     return x, rows, aux
+
+
+def _rows(cfg, spec, tapes, pdtype, policy):
+    """Profile rows [len(tapes), width] of the leading dense layers, as the
+    layers after them emit theirs (scanned: shortcut only)."""
+    if policy == "shortcut" or (policy != "off" and not cfg.scan_layers):
+        return jnp.stack([spec.emit(t, pdtype) for t in tapes])
+    return jnp.zeros((len(tapes), 0), pdtype)
+
+
+def _prefix_train(cfg, params, x, positions, spec, pdtype, policy):
+    """The leading dense layers, before the scanned stack.
+    Returns (x, their profile rows or None, aux)."""
+    aux = jnp.float32(0.0)
+    if "prefix" not in params:
+        return x, None, aux
+    tapes = []
+    for p_l in params["prefix"]:
+        x, tape, aux_l = block_apply_train(cfg, p_l, x, positions)
+        aux = aux + aux_l
+        tapes.append(tape)
+    return x, _rows(cfg, spec, tapes, pdtype, policy), aux
 
 
 @jax.named_scope("logits")
@@ -352,9 +407,22 @@ class KvCaches(NamedTuple):
     v: jnp.ndarray
 
 
-def kv_cache_init(cfg, batch: int, max_len: int) -> KvCaches:
-    dh = cfg.head_dim
+class LatentCaches(NamedTuple):
+    """MLA's cache: per position and layer the normalised latent and the
+    rotated rope key, [layers, B, Smax, kv_lora_rank + qk_rope_dim], for
+    the leading dense layers and for the scanned stack."""
+    prefix: jnp.ndarray
+    blocks: jnp.ndarray
+
+
+def kv_cache_init(cfg, batch: int, max_len: int):
     dt = jnp.dtype(cfg.activation_dtype)
+    if cfg.mla:
+        k = cfg.first_k_dense
+        tail = (batch, max_len, cfg.latent_dim)
+        return LatentCaches(jnp.zeros((k,) + tail, dt),
+                            jnp.zeros((cfg.n_layers - k,) + tail, dt))
+    dh = cfg.head_dim
     shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, dh)
     return KvCaches(jnp.zeros(shape, dt), jnp.zeros(shape, dt))
 
@@ -384,13 +452,23 @@ def lm_decode_step(cfg, params, caches, tokens, pos):
                else jnp.zeros((0,), pdtype))
         return xc, (new_cache, row)
 
-    if cfg.family == "ssm":
-        cache_tree = caches
+    if cfg.mla:
+        # the leading dense layers, then the stack
+        pre_caches, tapes = [], []
+        for p_l, cache_l in zip(params["prefix"], caches.prefix):
+            x, cache_l, tape = block_apply_decode(cfg, p_l, x, cache_l, pos)
+            pre_caches.append(cache_l)
+            tapes.append(tape)
+        x, (blocks, rows) = jax.lax.scan(body, x, (params["blocks"],
+                                                   caches.blocks))
+        new_caches = LatentCaches(jnp.stack(pre_caches), blocks)
+        rows = jnp.concatenate([_rows(cfg, spec, tapes, pdtype, policy), rows])
     else:
-        cache_tree = (caches.k, caches.v)
-    x, (new_caches, rows) = jax.lax.scan(body, x, (params["blocks"], cache_tree))
-    if cfg.family != "ssm":
-        new_caches = KvCaches(*new_caches)
+        cache_tree = caches if cfg.family == "ssm" else (caches.k, caches.v)
+        x, (new_caches, rows) = jax.lax.scan(body, x, (params["blocks"],
+                                                       cache_tree))
+        if cfg.family != "ssm":
+            new_caches = KvCaches(*new_caches)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = lm_logits(cfg, params, x)
     return logits, new_caches, rows
@@ -410,22 +488,25 @@ def lm_prefill(cfg, params, tokens):
             xc = xc + h
             # SSD final state is recomputed per layer for the cache below
             return xc, None
-        h, lmax, (k, v) = attn_apply_train(
+        h, lmax, kept = _attn_train(
             cfg, p_l["attn"], rms_norm(xc, p_l["norm1"], cfg.norm_eps),
             positions)
         xc = xc + h
-        h_in = rms_norm(xc, p_l["norm2"], cfg.norm_eps)
-        if cfg.family == "moe":
-            h, _, _ = moe_apply(p_l["moe"], h_in, top_k=cfg.top_k,
-                                capacity_factor=cfg.capacity_factor,
-                                activation=cfg.activation)
-        else:
-            h = mlp_apply(p_l["mlp"], h_in, cfg.activation)
+        h, _, _ = _ffn(cfg, p_l, rms_norm(xc, p_l["norm2"], cfg.norm_eps))
         xc = xc + h
-        return xc, (k, v)
+        return xc, kept
 
-    x, kv = jax.lax.scan(body, x, params["blocks"])
+    pre = []
+    for p_l in params.get("prefix", ()):
+        x, latent = body(x, p_l)
+        pre.append(latent)
+    x, kept = jax.lax.scan(body, x, params["blocks"])
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits_last = lm_logits(cfg, params, x[:, -1:, :])
-    caches = None if cfg.family == "ssm" else KvCaches(kv[0], kv[1])
+    if cfg.family == "ssm":
+        caches = None
+    elif cfg.mla:
+        caches = LatentCaches(jnp.stack(pre), kept)
+    else:
+        caches = KvCaches(kept[0], kept[1])
     return logits_last, caches

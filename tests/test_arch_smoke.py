@@ -24,6 +24,8 @@ EXPECTED_PARAMS_B = {
     # ~29B total / ~4.8B active.  (Upstream Moonlight-16B-A3B has 27 layers;
     # the assignment's layer count is authoritative here.)
     "moonshot-v1-16b-a3b": (26, 31),
+    # published Moonlight-16B-A3B: 27 layers, MLA, dense layer 0, 15.96B
+    "moonlight-16b-a3b": (15.5, 16.5),
     "qwen3-moe-235b-a22b": (220, 245),
     "mamba2-780m": (0.68, 0.88),
     "zamba2-1.2b": (1.0, 1.5),
@@ -62,7 +64,8 @@ def test_reduced_smoke_one_train_step(arch):
 
 
 @pytest.mark.parametrize("arch", ["chatglm3-6b", "qwen3-moe-235b-a22b",
-                                  "mamba2-780m", "zamba2-1.2b", "whisper-base"])
+                                  "moonlight-16b-a3b", "mamba2-780m",
+                                  "zamba2-1.2b", "whisper-base"])
 def test_reduced_smoke_decode_step(arch):
     from repro.models.api import decode_fn, init_caches
     cfg = get_config(arch).reduced()

@@ -72,6 +72,25 @@ def test_chatglm3_serve_step_fits_one_chip(one_chip):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM
 
 
+def test_moonlight_share_serve_step_fits_one_chip(one_chip):
+    """Published widths, all 27 layers, 8 of 64 experts held, batch 128 and
+    a 640-position latent cache: the moonlight.decode.inline cell's step."""
+    cfg = get_config("moonlight-16b-a3b").with_expert_share(0, 8)
+    params = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        model_specs(cfg), is_leaf=is_spec)
+    caches = _abstract(jax.eval_shape(lambda: init_caches(cfg, 128, 640)),
+                       one_chip)
+    tokens = jax.ShapeDtypeStruct((128, 1), jnp.int32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(make_serve_step(cfg), donate_argnums=(1,)).lower(
+        params, caches, tokens, pos).compile()
+    mem = compiled.memory_analysis()
+    # 6.73 GB of weights and the 2.55 GB latent cache
+    assert mem.argument_size_in_bytes > 9e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM
+
+
 def test_param_init_never_holds_a_float32_leaf(one_chip):
     """The largest chatglm3-6b leaf, [28, 4096, 13696] bf16, is drawn and
     cast in one fused program: no float32 copy of it is ever live."""
