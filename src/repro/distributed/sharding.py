@@ -111,7 +111,8 @@ def cache_axes(cfg):
     if cfg.mla:
         lat = (None, "batch", None, None)
         return LatentCaches(prefix=lat, blocks=lat)
-    return KvCaches(k=kv5, v=kv5)
+    kv = (None, "batch", "kv_heads", None, None)      # [L, B, KV, S, dh]
+    return KvCaches(k=kv, v=kv)
 
 
 def cache_shardings(cfg, mesh: Mesh, rules: Dict[str, Any], abs_caches):

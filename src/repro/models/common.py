@@ -5,6 +5,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 
 def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
@@ -62,6 +63,23 @@ def apply_rotary(
     y2 = x2 * cos + x1 * sin
     yr = jnp.stack([y1, y2], axis=-1).reshape(xr.shape).astype(x.dtype)
     return jnp.concatenate([yr, xp], axis=-1) if rot < dh else yr
+
+
+def write_in_place(stack, update, index):
+    """``stack`` with ``update`` written at ``index``, held in row-major
+    order: the write of a cache that a layer loop carries.
+
+    Left to choose, the TPU compiler lays a loop-carried buffer out for the
+    loop's own writes and reads, and copies the whole cache into and out of
+    that layout around the loop.  Held row-major, the loop keeps the layout
+    the device gives the donated cache, which is row-major when the cache's
+    two minor dimensions are whole (8, 128) tiles and the minor one is a
+    multiple of 128 wide (``kv_cache_init`` shapes the caches so), and the
+    write touches only ``update``'s tiles.
+    """
+    out = jax.lax.dynamic_update_slice(stack, update.astype(stack.dtype),
+                                       index)
+    return with_layout_constraint(out, Layout(tuple(range(out.ndim))))
 
 
 def silu(x):

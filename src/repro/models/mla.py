@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from .attention import NEG_INF, attention
-from .common import apply_rotary, rms_norm
+from .common import apply_rotary, rms_norm, write_in_place
 from .params import ParamSpec
 
 
@@ -92,16 +92,19 @@ def mla_apply_train(cfg, p, x, positions):
 
 
 @jax.named_scope("attn")
-def mla_apply_decode(cfg, p, x, cache, pos):
-    """Absorbed path: one token against the latent cache [B, Smax, r + dr],
-    writing position ``pos``.  Returns (out, logit_max, cache)."""
+def mla_apply_decode(cfg, p, x, caches, layer, pos):
+    """Absorbed path: one token against layer ``layer`` of the latent caches
+    stacked over layers, [L, B, Smax, row] (rows of ``r + dr`` padded to
+    the lane width).  Writes position ``pos`` of that layer in place, then
+    reads the layer back.  Returns (out, logit_max, caches)."""
     B = x.shape[0]
     H, r, dn = cfg.n_heads, cfg.kv_lora_rank, cfg.qk_nope_dim
     positions = jnp.full((B, 1), pos, jnp.int32)
     q_nope, q_pe, latent = _project(cfg, p, x, positions)
     with jax.named_scope("latent_update"):
-        cache = jax.lax.dynamic_update_slice(
-            cache, latent.astype(cache.dtype), (0, pos, 0))
+        caches = write_in_place(caches, latent[None], (layer, 0, pos, 0))
+    cache = jax.lax.dynamic_index_in_dim(caches, layer, keepdims=False)
+    cache = cache[..., :latent.shape[-1]]                    # [B, Smax, r+dr]
     wkv_b = p["wkv_b"].reshape(r, H, -1)
     q_lat = jnp.einsum("bthn,rhn->bthr", q_nope, wkv_b[..., :dn])
     q_cat = jnp.concatenate([q_lat, q_pe], axis=-1)         # [B, 1, H, r+dr]
@@ -113,4 +116,4 @@ def mla_apply_decode(cfg, p, x, cache, pos):
     w = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
     o_lat = jnp.einsum("bhts,bsr->bthr", w, cache[..., :r])
     out = jnp.einsum("bthr,rhv->bthv", o_lat, wkv_b[..., dn:])
-    return out.reshape(B, 1, -1) @ p["wo"], lmax, cache
+    return out.reshape(B, 1, -1) @ p["wo"], lmax, caches

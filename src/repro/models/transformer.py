@@ -19,7 +19,7 @@ from ..core import Label, ProfileStream, TapeSpec, rows_to_stream
 from ..core.stream import validate_policy
 from .attention import attention, decode_attention
 from ..distributed.ctx import shard_act
-from .common import apply_rotary, rms_norm
+from .common import apply_rotary, rms_norm, write_in_place
 from .mla import mla_apply_decode, mla_apply_train, mla_specs
 from .mlp import mlp_apply, mlp_specs
 from .moe import expert_share_apply, moe_apply, moe_specs
@@ -174,19 +174,23 @@ def attn_apply_train(cfg, p, x, positions):
 
 
 @jax.named_scope("attn")
-def attn_apply_decode(cfg, p, x, k_cache, v_cache, pos):
-    """One-token attention against the cache; writes position ``pos``."""
+def attn_apply_decode(cfg, p, x, k_cache, v_cache, layer, pos):
+    """One-token attention against layer ``layer`` of the caches stacked
+    over layers, [L, B, KV, Smax, dh].  Writes position ``pos`` of that
+    layer in place, then reads the layer back.  Returns
+    (out, logit_max, (k_cache, v_cache))."""
     B = x.shape[0]
     positions = jnp.full((B, 1), pos, jnp.int32)
     q, k, v = _attn_project(cfg, p, x)
     q = apply_rotary(q, positions, cfg.rope_theta, cfg.rotary_fraction)
     k = apply_rotary(k, positions, cfg.rope_theta, cfg.rotary_fraction)
+    at = (layer, 0, 0, pos, 0)
     with jax.named_scope("kv_update"):
-        k_cache = jax.lax.dynamic_update_slice(
-            k_cache, k.astype(k_cache.dtype), (0, pos, 0, 0))
-        v_cache = jax.lax.dynamic_update_slice(
-            v_cache, v.astype(v_cache.dtype), (0, pos, 0, 0))
-    out, lmax = decode_attention(q, k_cache, v_cache, pos + 1)
+        k_cache = write_in_place(k_cache, jnp.swapaxes(k, 1, 2)[None], at)
+        v_cache = write_in_place(v_cache, jnp.swapaxes(v, 1, 2)[None], at)
+    k_l, v_l = (jnp.swapaxes(jax.lax.dynamic_index_in_dim(
+        c, layer, keepdims=False), 1, 2) for c in (k_cache, v_cache))
+    out, lmax = decode_attention(q, k_l, v_l, pos + 1)
     return out.reshape(B, 1, -1) @ p["wo"], lmax, (k_cache, v_cache)
 
 
@@ -236,8 +240,10 @@ def block_apply_train(cfg, p, x, positions):
     return x, tape, aux
 
 
-def block_apply_decode(cfg, p, x, cache, pos):
-    """cache: (k, v) tensors, the MLA latent cache or SsmCache.
+def block_apply_decode(cfg, p, x, cache, layer, pos):
+    """cache: the (k, v) tensors or the MLA latent cache stacked over
+    layers, of which this block writes position ``pos`` of ``layer`` in
+    place; or this layer's SsmCache (``layer`` unused).
     Returns (x, cache, tape)."""
     tape: Dict[str, jnp.ndarray] = {}
     if cfg.family == "ssm":
@@ -249,10 +255,10 @@ def block_apply_decode(cfg, p, x, cache, pos):
         x_in = rms_norm(x, p["norm1"], cfg.norm_eps)
         if cfg.mla:
             h, lmax, new_cache = mla_apply_decode(cfg, p["attn"], x_in,
-                                                  cache, pos)
+                                                  cache, layer, pos)
         else:
             h, lmax, new_cache = attn_apply_decode(cfg, p["attn"], x_in,
-                                                   *cache, pos)
+                                                   *cache, layer, pos)
         x = x + h
         tape["attn_logit_max"] = lmax[None]
         h, _, prof = _ffn(cfg, p, rms_norm(x, p["norm2"], cfg.norm_eps))
@@ -403,27 +409,40 @@ def assemble_stream(cfg, rows) -> Optional[ProfileStream]:
 # serving: prefill + decode
 # --------------------------------------------------------------------------- #
 class KvCaches(NamedTuple):
-    k: jnp.ndarray   # [L, B, Smax, KV, dh]
+    """Keys and values per layer and position, [L, B, KV, Smax, dh]: one
+    kv head's positions are rows of ``dh``, as decode attention reads
+    them.  The decode step writes one position a layer in place
+    (``lm_decode_step``)."""
+    k: jnp.ndarray
     v: jnp.ndarray
 
 
 class LatentCaches(NamedTuple):
-    """MLA's cache: per position and layer the normalised latent and the
-    rotated rope key, [layers, B, Smax, kv_lora_rank + qk_rope_dim], for
-    the leading dense layers and for the scanned stack."""
+    """MLA's cache: per layer and position the normalised latent and the
+    rotated rope key, [layers, B, Smax, latent_row(cfg)], for the leading
+    dense layers and for the scanned stack.  The decode step writes one
+    position a layer of each in place (``lm_decode_step``)."""
     prefix: jnp.ndarray
     blocks: jnp.ndarray
+
+
+def latent_row(cfg) -> int:
+    """A latent cache row: ``kv_lora_rank + qk_rope_dim`` padded to a
+    multiple of 128.  The TPU keeps a minor dimension of that width minor,
+    so the cache stays row-major on the device, as ``write_in_place``
+    needs; the padding is written once, as zeros, and never read."""
+    return -(-cfg.latent_dim // 128) * 128
 
 
 def kv_cache_init(cfg, batch: int, max_len: int):
     dt = jnp.dtype(cfg.activation_dtype)
     if cfg.mla:
         k = cfg.first_k_dense
-        tail = (batch, max_len, cfg.latent_dim)
+        tail = (batch, max_len, latent_row(cfg))
         return LatentCaches(jnp.zeros((k,) + tail, dt),
                             jnp.zeros((cfg.n_layers - k,) + tail, dt))
     dh = cfg.head_dim
-    shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, dh)
+    shape = (cfg.n_layers, batch, cfg.n_kv_heads, max_len, dh)
     return KvCaches(jnp.zeros(shape, dt), jnp.zeros(shape, dt))
 
 
@@ -437,6 +456,14 @@ def ssm_caches_init(cfg, batch: int):
 def lm_decode_step(cfg, params, caches, tokens, pos):
     """One decode step.  tokens: [B, 1]; caches stacked over layers.
 
+    The position-indexed caches (``KvCaches``, ``LatentCaches``) ride in
+    the layer scan's carry, and each layer writes its one position into
+    the stacked buffer in place before reading its layer back.  Passed
+    through the scan's ``xs`` and ``ys`` instead, they would be a second
+    stacked buffer that every layer rewrites a whole slice of and that is
+    copied back into the donated cache after the loop.  An ``SsmCache``
+    changes whole every step, so it is scanned over as ``xs`` and ``ys``.
+
     Returns (logits [B, 1, V], caches, rows).
     """
     spec = tape_spec_for(cfg)
@@ -444,31 +471,46 @@ def lm_decode_step(cfg, params, caches, tokens, pos):
     policy = validate_policy(cfg.profile_policy)
     x = _embed(cfg, params, tokens)
 
-    def body(carry, per_layer):
-        xc = carry
-        p_l, cache_l = per_layer
-        xc, new_cache, tape = block_apply_decode(cfg, p_l, xc, cache_l, pos)
-        row = (spec.emit(tape, pdtype) if policy == "shortcut"
-               else jnp.zeros((0,), pdtype))
-        return xc, (new_cache, row)
+    def emit(tape):
+        return (spec.emit(tape, pdtype) if policy == "shortcut"
+                else jnp.zeros((0,), pdtype))
 
-    if cfg.mla:
-        # the leading dense layers, then the stack
-        pre_caches, tapes = [], []
-        for p_l, cache_l in zip(params["prefix"], caches.prefix):
-            x, cache_l, tape = block_apply_decode(cfg, p_l, x, cache_l, pos)
-            pre_caches.append(cache_l)
-            tapes.append(tape)
-        x, (blocks, rows) = jax.lax.scan(body, x, (params["blocks"],
-                                                   caches.blocks))
-        new_caches = LatentCaches(jnp.stack(pre_caches), blocks)
-        rows = jnp.concatenate([_rows(cfg, spec, tapes, pdtype, policy), rows])
+    if cfg.family == "ssm":
+        def ssm_body(xc, per_layer):
+            p_l, cache_l = per_layer
+            xc, cache_l, tape = block_apply_decode(cfg, p_l, xc, cache_l,
+                                                   None, pos)
+            return xc, (cache_l, emit(tape))
+
+        x, (new_caches, rows) = jax.lax.scan(ssm_body, x,
+                                             (params["blocks"], caches))
     else:
-        cache_tree = caches if cfg.family == "ssm" else (caches.k, caches.v)
-        x, (new_caches, rows) = jax.lax.scan(body, x, (params["blocks"],
-                                                       cache_tree))
-        if cfg.family != "ssm":
-            new_caches = KvCaches(*new_caches)
+        def body(carry, per_layer):
+            xc, stack = carry
+            p_l, layer = per_layer
+            xc, stack, tape = block_apply_decode(cfg, p_l, xc, stack,
+                                                 layer, pos)
+            return (xc, stack), emit(tape)
+
+        if cfg.mla:
+            # the leading dense layers, then the stack
+            prefix, tapes = caches.prefix, []
+            for layer, p_l in enumerate(params["prefix"]):
+                x, prefix, tape = block_apply_decode(cfg, p_l, x, prefix,
+                                                     layer, pos)
+                tapes.append(tape)
+            stack = caches.blocks
+        else:
+            stack = (caches.k, caches.v)
+        layers = jnp.arange(cfg.n_layers - cfg.first_k_dense)
+        (x, stack), rows = jax.lax.scan(body, (x, stack),
+                                        (params["blocks"], layers))
+        if cfg.mla:
+            new_caches = LatentCaches(prefix, stack)
+            rows = jnp.concatenate([_rows(cfg, spec, tapes, pdtype, policy),
+                                    rows])
+        else:
+            new_caches = KvCaches(*stack)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = lm_logits(cfg, params, x)
     return logits, new_caches, rows
@@ -506,7 +548,8 @@ def lm_prefill(cfg, params, tokens):
     if cfg.family == "ssm":
         caches = None
     elif cfg.mla:
-        caches = LatentCaches(jnp.stack(pre), kept)
+        pad = [(0, 0)] * 3 + [(0, latent_row(cfg) - cfg.latent_dim)]
+        caches = LatentCaches(jnp.pad(jnp.stack(pre), pad), jnp.pad(kept, pad))
     else:
-        caches = KvCaches(kept[0], kept[1])
+        caches = KvCaches(*(jnp.swapaxes(c, 2, 3) for c in kept))
     return logits_last, caches

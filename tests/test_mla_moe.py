@@ -89,7 +89,9 @@ def test_absorbed_decode_matches_the_plain_reference(seed, shards):
     cfg, params, toks, want = setup(seed, shards)
     B, T = toks.shape
     caches = init_caches(cfg, B, T)
-    assert caches.blocks.shape[-1] == cfg.kv_lora_rank + cfg.qk_rope_dim
+    # a row holds the latent and the rope key, padded to 128 lanes
+    assert caches.blocks.shape[-1] == -(-(cfg.kv_lora_rank
+                                         + cfg.qk_rope_dim) // 128) * 128
     got = []
     for t in range(T):
         lg, caches, _ = decode_fn(cfg, params, caches, toks[:, t:t + 1], t)

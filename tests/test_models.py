@@ -1,10 +1,14 @@
 """Model-zoo correctness: attention paths, SSD, MoE, decode consistency."""
+import dataclasses
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from _hypothesis_shim import given, settings, st
 
+from repro.configs import get_config
 from repro.configs.base import ModelConfig
 from repro.models import init_params
 from repro.models.attention import (
@@ -12,7 +16,11 @@ from repro.models.attention import (
 )
 from repro.models.moe import capacity_for, moe_apply, moe_specs
 from repro.models.ssm import ssd_chunked, ssd_reference
-from repro.models.transformer import (assemble_stream, kv_cache_init, lm_decode_step, lm_loss, lm_specs, ssm_caches_init)
+from repro.models.api import init_caches, model_specs
+from repro.models.transformer import (
+    _rows, assemble_stream, block_apply_decode, kv_cache_init, lm_decode_step,
+    lm_loss, lm_specs, ssm_caches_init, tape_spec_for)
+from repro.models.common import rms_norm
 
 
 def rand(key, *shape, dtype=jnp.float32):
@@ -198,6 +206,107 @@ def test_decode_matches_prefill_logits(family, extra):
     dec_logits = jnp.stack(outs, axis=1)
     np.testing.assert_allclose(np.asarray(dec_logits),
                                np.asarray(full_logits), rtol=2e-3, atol=2e-3)
+
+
+_block = jax.jit(block_apply_decode, static_argnums=0)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _head(cfg, params, x):
+    from repro.models.transformer import lm_logits
+    return lm_logits(cfg, params,
+                     rms_norm(x, params["final_norm"], cfg.norm_eps))
+
+
+def _take(tree, i):
+    return jax.tree_util.tree_map(lambda a: a[i], tree)
+
+
+def _put(tree, i, new):
+    return jax.tree_util.tree_map(lambda a, n: a.at[i].set(n), tree, new)
+
+
+def _per_layer_decode(cfg, params, caches, tokens, pos):
+    """``lm_decode_step`` as a Python loop over layers: each layer's
+    weights and cache slice taken out by indexing, the block applied to
+    that one layer (one program a layer, so that layer outputs are rounded
+    to their dtype as the scan's carry is), and the slice put back by
+    indexing."""
+    spec, pdtype = tape_spec_for(cfg), jnp.dtype(cfg.profile_dtype)
+    x = params["embed"][tokens].astype(jnp.dtype(cfg.activation_dtype))
+    if cfg.family == "ssm":
+        stacks, tapes = caches, []
+        for i in range(cfg.n_layers):
+            x, new, tape = _block(cfg, _take(params["blocks"], i), x,
+                                  _take(stacks, i), None, pos)
+            stacks = _put(stacks, i, new)
+            tapes.append(tape)
+        new_caches = stacks
+    else:
+        stacks = ([caches.prefix, caches.blocks] if cfg.mla
+                  else [(caches.k, caches.v)])
+        layers = ([(0, p) for p in params.get("prefix", ())]
+                  + [(len(stacks) - 1, _take(params["blocks"], i))
+                     for i in range(cfg.n_layers - cfg.first_k_dense)])
+        tapes, seen = [], [0] * len(stacks)
+        for which, p_l in layers:
+            i = seen[which]
+            one = jax.tree_util.tree_map(lambda a: a[i][None], stacks[which])
+            x, one, tape = _block(cfg, p_l, x, one, 0, pos)
+            stacks[which] = _put(stacks[which], i, _take(one, 0))
+            seen[which] += 1
+            tapes.append(tape)
+        new_caches = type(caches)(*(stacks if cfg.mla else stacks[0]))
+    rows = _rows(cfg, spec, tapes, pdtype, cfg.profile_policy)
+    return _head(cfg, params, x), new_caches, rows
+
+
+DECODE_ARCHS = {
+    "dense": lambda: get_config("chatglm3-6b").reduced(),
+    "softmax-moe": lambda: get_config("qwen3-moe-235b-a22b").reduced(),
+    "mla-share": lambda: get_config(
+        "moonlight-16b-a3b").reduced().with_expert_share(0, 8),
+    "ssm": lambda: get_config("mamba2-780m").reduced(),
+}
+
+
+@pytest.mark.parametrize("policy", ["off", "shortcut", "inline"])
+@pytest.mark.parametrize("arch", sorted(DECODE_ARCHS))
+def test_decode_step_matches_per_layer_reference(arch, policy):
+    """The scanned step, carrying the stacked caches and writing one
+    position a layer in place, equals a loop over layers that updates each
+    layer's slice by indexing: logits, every cache tensor and the profile
+    rows bit for bit, at the first, a middle and the last position; the
+    caches change at that position alone."""
+    cfg = dataclasses.replace(DECODE_ARCHS[arch](), profile_policy=policy)
+    params = init_params(model_specs(cfg), jax.random.PRNGKey(0))
+    B, S = 3, 9
+    zeros = init_caches(cfg, B, S)
+    keys = jax.random.split(jax.random.PRNGKey(1),
+                            len(jax.tree_util.tree_leaves(zeros)))
+    caches = jax.tree_util.tree_unflatten(
+        jax.tree_util.tree_structure(zeros),
+        [jax.random.normal(k, a.shape).astype(a.dtype) for k, a in
+         zip(keys, jax.tree_util.tree_leaves(zeros))])
+    step = jax.jit(lambda c, t, p: lm_decode_step(cfg, params, c, t, p))
+    for pos in (0, S // 2, S - 1):
+        toks = jax.random.randint(jax.random.PRNGKey(pos), (B, 1), 0,
+                                  cfg.vocab_size)
+        got = step(caches, toks, pos)
+        want = _per_layer_decode(cfg, params, caches, toks, pos)
+        assert (jax.tree_util.tree_structure(got)
+                == jax.tree_util.tree_structure(want))
+        for g, w in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want)):
+            assert g.shape == w.shape and g.dtype == w.dtype
+            np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                          np.asarray(w, np.float32))
+        if cfg.family != "ssm":
+            s_axis = 2 if cfg.mla else 3
+            for new, old in zip(got[1], caches):
+                kept = np.delete(np.asarray(new, np.float32), pos, s_axis)
+                np.testing.assert_array_equal(
+                    kept, np.delete(np.asarray(old, np.float32), pos, s_axis))
 
 
 def test_profile_stream_assembles_with_labels():
