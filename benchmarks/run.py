@@ -10,8 +10,8 @@ import sys
 import time
 from pathlib import Path
 
-BENCHES = ("fig3", "fig4", "table1", "fig5", "roofline", "perf_stream",
-           "trace_smoke", "analysis_smoke")
+BENCHES = ("fig3", "fig4", "table1", "fig5", "roofline", "trace_smoke",
+           "analysis_smoke")
 
 
 def main() -> None:
@@ -31,8 +31,6 @@ def main() -> None:
             from benchmarks import fig5_patterns as mod
         elif name == "roofline":
             from benchmarks import roofline as mod
-        elif name == "perf_stream":
-            from benchmarks import perf_stream as mod
         elif name == "trace_smoke":
             from benchmarks import trace_smoke as mod
         elif name == "analysis_smoke":

@@ -16,7 +16,7 @@ One chip, in order:
   simulator  a 256-lane fault/capacity campaign of one RINN through
              ``run_sim_batch``; fault-free lanes must match the independent
              NumPy executor ``bounded_replay``, faulted lanes must be
-             bit-identical to ``run_sim_single``.
+             bit-identical to one-lane ``run_sim`` calls.
 
 ``--four-chips`` runs only ``run_train`` on a 2x2 (data, model) mesh:
 chatglm3-6b at published widths cut to 12 layers for 3 steps (weights and
@@ -205,8 +205,7 @@ def sim_phase(lanes: int, fault_free: int, single_checks: int) -> None:
     from repro.analysis import analyze_sim, bounded_replay
     from repro.analysis.modelcheck import _Packed
     from repro.rinn import (RinnConfig, ZCU102, compile_graph, generate_rinn,
-                            run_sim_batch)
-    from repro.rinn.batchsim import run_sim_single
+                            run_sim, run_sim_batch)
 
     sim = compile_graph(generate_rinn(RinnConfig(**SIM_CONFIG)), ZCU102)
     analysis = analyze_sim(sim)
@@ -240,14 +239,13 @@ def sim_phase(lanes: int, fault_free: int, single_checks: int) -> None:
               f"{ref.completed}, cycles={cycles}) or in its FIFO maxima")
     for i in np.linspace(fault_free, lanes - 1, single_checks).astype(int):
         plan, caps, profiled = specs[i]
-        one = run_sim_single(sim, profiled=profiled, faults=plan,
-                             capacity_overrides=caps)
-        check(one == results[i], f"faulted lane {i} differs from "
-                                 f"run_sim_single")
+        one = run_sim(sim, profiled=profiled, faults=plan,
+                      capacity_overrides=caps)
+        check(one == results[i], f"faulted lane {i} differs from run_sim")
     say("simulator", f"{fault_free} fault-free lanes match bounded_replay "
                      f"(completed, cycles, per-FIFO maxima); "
                      f"{single_checks} faulted lanes bit-identical to "
-                     f"run_sim_single")
+                     f"run_sim")
 
 
 # --------------------------------------------------------------------- #

@@ -21,6 +21,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro.rinn.batchsim import run_sim
 from repro.trace import TraceStore, diff_traces, edge_name
 
 from .dataflow import StaticAnalysis
@@ -235,8 +236,6 @@ def grade_decidability(
     cycle, a ``deadlock`` certificate must replay to the certified stall
     (:meth:`~repro.analysis.modelcheck.DeadlockCertificate.confirm`).
     """
-    from repro.rinn.streamsim import run_sim
-
     outcomes: List[DecisionOutcome] = []
     for label, caps in capacity_maps.items():
         res = analysis.check(caps, profiled=profiled)

@@ -49,6 +49,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro.rinn.batchsim import run_sim
 from repro.rinn.streamsim import CompiledSim, FaultPlan
 
 Edge = Tuple[str, str]
@@ -135,7 +136,7 @@ def bounded_replay(sim: CompiledSim, capacities: Dict[Edge, int], *,
     the minimum pending timer, and a state where nothing fires and no
     timer is pending is a permanent fixpoint (the machine is deterministic
     and fire-free cycles change nothing but timers).  Completion cycles are
-    bit-identical to :func:`repro.rinn.streamsim.run_sim`.
+    bit-identical to :func:`repro.rinn.batchsim.run_sim`.
     """
     p = _packed if _packed is not None else _Packed(sim, profiled)
     cap = _cap_array(p, capacities)
@@ -280,8 +281,6 @@ class DeadlockCertificate:
     def confirm(self, sim: CompiledSim) -> bool:
         """Replay the prefix through ``run_sim`` and check it stalls in the
         certified state (same occupancies, same per-actor progress)."""
-        from repro.rinn.streamsim import run_sim
-
         res = run_sim(sim, profiled=self.profiled,
                       max_cycles=self.replay_max_cycles,
                       capacity_overrides=dict(self.capacities))

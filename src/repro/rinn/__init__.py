@@ -13,12 +13,11 @@ from .build import (
 from .streamsim import (
     BeatFault, CapacityFault, CompiledSim, FaultPlan, NodeStall, SimResult,
     WordCorruption, compile_graph, critical_path_actors, critical_path_edges,
-    run_sim,
 )
 from .batchsim import (
     FaultOps, MachineOps, ShapeBucket, TraceBuffers, compile_stats,
-    machine_bucket, reset_compile_stats, run_sim_batch, run_sim_many,
-    run_sim_traced, run_sim_traced_batch,
+    machine_bucket, reset_compile_stats, run_sim, run_sim_batch,
+    run_sim_many, run_sim_traced, run_sim_traced_batch,
 )
 from .cosim import (
     BlockedActor, CosimReport, DeadlockError, DeadlockReport, FifoRow,

@@ -18,14 +18,12 @@ This module splits the machine into two runtime pytrees:
     corruption (cycle, mask), the ``profiled`` interference flag, and the
     loop bounds (``max_cycles``, ``idle_limit``).
 
-Three jitted entry points share the simulator body:
-
-  * ``run_sim_single``   — one machine, one fault lane (powers ``run_sim``);
-  * ``run_sim_batch``    — one machine, B fault lanes via ``jax.vmap``
-    (``in_axes=(None, 0)``): a whole fault campaign, a capacity ladder, or
-    the unprofiled+profiled cosim pair is ONE device program;
-  * ``run_sim_many``     — B machines × B fault lanes (``in_axes=(0, 0)``)
-    for sweeps over different graphs that share a shape bucket.
+One launch core packs a list of lanes, runs them as one device program and
+unpacks the results.  One lane runs unbatched (``run_sim``); B lanes of
+one machine run under ``jax.vmap`` with ``in_axes=(None, 0)``
+(``run_sim_batch``: a fault campaign, a ladder rung or the cosim pair is
+ONE program); B machines of one shape bucket run with ``in_axes=(0, 0)``
+(``run_sim_many``).  Each program has a traced twin (``run_sim_traced*``).
 
 Padding is semantically inert: padded actors have ``total_in = total_out =
 0`` so they never consume, never produce, and count as finished; padded
@@ -34,10 +32,10 @@ under ``vmap`` comes from JAX's ``while_loop`` batching rule (finished
 lanes freeze), so batched results are bit-identical to sequential runs.
 
 ``compile_stats()`` exposes trace/launch counters so tests and the
-``perf_stream`` benchmark can assert cache behaviour.  Every launch also
-writes two host spans for ``jax.profiler``: ``sim.pack`` (packing the
-machine and its lanes, up to the jitted call) and ``sim.unpack`` (the
-host outputs into ``SimResult``\\ s).
+benchmark can assert cache behaviour.  Every launch also writes two host
+spans for ``jax.profiler``: ``sim.pack`` (packing the machine and its
+lanes, up to the jitted call) and ``sim.unpack`` (the host outputs into
+``SimResult``\\ s).
 """
 from __future__ import annotations
 
@@ -388,26 +386,24 @@ def _simulate(m: MachineOps, f: FaultOps, trace=None):
     return outs
 
 
-_jit_single = jax.jit(_simulate)
-_jit_lanes = jax.jit(jax.vmap(_simulate, in_axes=(None, 0)))
-_jit_machines = jax.jit(jax.vmap(_simulate, in_axes=(0, 0)))
-
-
 @functools.lru_cache(maxsize=None)
-def _traced_jits(t_slots: int):
-    """Jitted traced entry points for one (static) window count.
+def _program(windows: Optional[int], in_axes: Optional[Tuple]):
+    """The jitted simulator for one window count (``None``: untraced) and
+    one batching (``None``: one lane; else ``jax.vmap`` over ``(machine,
+    faults)`` with these ``in_axes``).
 
-    ``t_slots`` sizes the windowed accumulators and is therefore part of
-    the jit cache key; the window stride stays a runtime scalar, so
+    ``windows`` sizes the traced run's accumulators and is therefore part
+    of the jit cache key; the window stride stays a runtime scalar, so
     re-running with a different stride (or machine in the same shape
     bucket) does not recompile.
     """
+    def traced(m, f, stride):
+        return _simulate(m, f, trace=(stride, windows))
 
-    def single(m, f, stride):
-        return _simulate(m, f, trace=(stride, t_slots))
-
-    return (jax.jit(single),
-            jax.jit(jax.vmap(single, in_axes=(None, 0, None))))
+    fn, tail = (_simulate, ()) if windows is None else (traced, (None,))
+    if in_axes is not None:
+        fn = jax.vmap(fn, in_axes=in_axes + tail)
+    return jax.jit(fn)
 
 
 # --------------------------------------------------------------------- #
@@ -442,86 +438,6 @@ def _unpack(sim: CompiledSim, cap_np: np.ndarray, plan: Optional[FaultPlan],
     )
 
 
-# --------------------------------------------------------------------- #
-# public entry points
-# --------------------------------------------------------------------- #
-def run_sim_single(
-    sim: CompiledSim, profiled: bool = False, max_cycles: int = 200_000,
-    faults: Optional[FaultPlan] = None,
-    capacity_overrides: Optional[Dict[Edge, int]] = None,
-) -> SimResult:
-    """One run through the cached executable (the engine behind ``run_sim``)."""
-    with jax.profiler.TraceAnnotation("sim.pack"):
-        plan = faults or FaultPlan()
-        bucket = machine_bucket(sim, _stall_slots(plan))
-        machine = _to_device(pack_machine(sim, bucket))
-        ops, cap_np, idle_limit = pack_faults(
-            sim, bucket, plan, capacity_overrides, profiled, max_cycles)
-        ops = _to_device(ops)
-    _STATS["launches"] += 1
-    _STATS["lanes"] += 1
-    outs = [np.asarray(o) for o in _jit_single(machine, ops)]
-    with jax.profiler.TraceAnnotation("sim.unpack"):
-        return _unpack(sim, cap_np, faults, profiled, idle_limit, outs)
-
-
-def _broadcast(value, n: int, name: str) -> list:
-    if isinstance(value, (list, tuple)):
-        if len(value) != n:
-            raise ValueError(f"{name} has {len(value)} entries, expected {n}")
-        return list(value)
-    return [value] * n
-
-
-def run_sim_batch(
-    sim: CompiledSim, *,
-    plans: Union[None, FaultPlan, Sequence[Optional[FaultPlan]]] = None,
-    capacity_overrides: Union[
-        None, Dict[Edge, int], Sequence[Optional[Dict[Edge, int]]]] = None,
-    profiled: Union[bool, Sequence[bool]] = False,
-    max_cycles: Union[int, Sequence[int]] = 200_000,
-    n: Optional[int] = None,
-) -> List[SimResult]:
-    """Run B fault/capacity/profiled lanes of one machine as a single
-    vmapped device program.
-
-    Any of ``plans`` / ``capacity_overrides`` / ``profiled`` / ``max_cycles``
-    may be a sequence (all sequences must agree on length) or a scalar
-    (broadcast).  ``n`` forces the lane count when everything is scalar.
-    Results are bit-identical to calling :func:`run_sim_single` per lane.
-    """
-    lengths = [len(v) for v in (plans, capacity_overrides, profiled,
-                                max_cycles)
-               if isinstance(v, (list, tuple))]
-    if n is None:
-        n = max(lengths) if lengths else 1
-    plans_l = _broadcast(plans, n, "plans")
-    caps_l = _broadcast(capacity_overrides, n, "capacity_overrides")
-    prof_l = _broadcast(profiled, n, "profiled")
-    mc_l = _broadcast(max_cycles, n, "max_cycles")
-    if n == 1:
-        return [run_sim_single(sim, profiled=prof_l[0], max_cycles=mc_l[0],
-                               faults=plans_l[0],
-                               capacity_overrides=caps_l[0])]
-
-    with jax.profiler.TraceAnnotation("sim.pack"):
-        stall_slots = max(_stall_slots(p or FaultPlan()) for p in plans_l)
-        bucket = machine_bucket(sim, stall_slots)
-        machine = _to_device(pack_machine(sim, bucket))
-        packed = [pack_faults(sim, bucket, p or FaultPlan(), c, pr, mc)
-                  for p, c, pr, mc in zip(plans_l, caps_l, prof_l, mc_l)]
-        stacked = _stack([ops for ops, _, _ in packed])
-    _STATS["launches"] += 1
-    _STATS["lanes"] += n
-    outs = [np.asarray(o) for o in _jit_lanes(machine, stacked)]
-    with jax.profiler.TraceAnnotation("sim.unpack"):
-        return [
-            _unpack(sim, packed[b][1], plans_l[b], prof_l[b], packed[b][2],
-                    [o[b] for o in outs])
-            for b in range(n)
-        ]
-
-
 class TraceBuffers(NamedTuple):
     """Raw windowed trace of one run — the feed for :mod:`repro.trace`.
 
@@ -552,6 +468,117 @@ def _trim_trace(sim: CompiledSim, stride: int, cycles: int,
         window_cycles=tr_cyc[:w_used])
 
 
+def _launch(lanes: Sequence[tuple],
+            trace: Optional[Tuple[int, int]] = None) -> list:
+    """Pack, count, run and unpack one device program over ``lanes``, each
+    ``(sim, plan, capacity_overrides, profiled, max_cycles)``.
+
+    The program follows from what the lanes are: one lane runs unbatched,
+    lanes of one machine run lane-vmapped, and lanes of several machines
+    (which must share a shape bucket) run machine-vmapped.  ``trace`` is
+    ``None`` or ``(windows, stride)``.  Returns one ``SimResult`` per lane
+    in lane order, paired with its :class:`TraceBuffers` when traced.
+    """
+    sims = [lane[0] for lane in lanes]
+    single = len(lanes) == 1
+    one_machine = all(s is sims[0] for s in sims)
+    with jax.profiler.TraceAnnotation("sim.pack"):
+        bucket = machine_bucket(sims[0], max(
+            _stall_slots(lane[1] or FaultPlan()) for lane in lanes))
+        packed = [pack_faults(sim, bucket, plan or FaultPlan(), caps, prof, mc)
+                  for sim, plan, caps, prof, mc in lanes]
+        machine = (_to_device(pack_machine(sims[0], bucket)) if one_machine
+                   else _stack([pack_machine(sim, bucket) for sim in sims]))
+        ops = (_to_device(packed[0][0]) if single
+               else _stack([p[0] for p in packed]))
+    program = _program(None if trace is None else trace[0],
+                       None if single else (None, 0) if one_machine
+                       else (0, 0))
+    args = (machine, ops) if trace is None else (
+        machine, ops, jnp.int32(trace[1]))
+    _STATS["launches"] += 1
+    _STATS["lanes"] += len(lanes)
+    outs = [np.asarray(o) for o in program(*args)]
+    results = []
+    with jax.profiler.TraceAnnotation("sim.unpack"):
+        for b, (sim, plan, _, prof, _) in enumerate(lanes):
+            lane_outs = outs if single else [o[b] for o in outs]
+            res = _unpack(sim, packed[b][1], plan, prof, packed[b][2],
+                          lane_outs[:7])
+            results.append(res if trace is None else (res, _trim_trace(
+                sim, trace[1], res.cycles, lane_outs[7:])))
+    return results
+
+
+# --------------------------------------------------------------------- #
+# public entry points
+# --------------------------------------------------------------------- #
+def run_sim(
+    sim: CompiledSim, profiled: bool = False, max_cycles: int = 200_000,
+    faults: Optional[FaultPlan] = None,
+    capacity_overrides: Optional[Dict[Edge, int]] = None,
+) -> SimResult:
+    """Execute the dataflow machine once; pure JAX control flow inside.
+
+    ``faults`` injects the plan's stalls / beat faults / capacity faults /
+    profile-word bit flips; ``capacity_overrides`` grows or shrinks specific
+    edges' FIFOs (the remediation hook — it wins over the plan's capacity
+    faults).  A no-progress detector stops the loop once no actor has fired
+    for longer than any legitimate quiet period, so deadlocks terminate in
+    O(deadlock cycle) rather than O(max_cycles).
+
+    Fault plans, capacities, and the ``profiled`` flag are *runtime
+    arguments* of a jit-cached executable keyed on the padded machine
+    shape: re-running on the same shape bucket with a different plan /
+    override / flag does not recompile.
+    """
+    return _launch([(sim, faults, capacity_overrides, profiled,
+                     max_cycles)])[0]
+
+
+def _broadcast(value, n: int, name: str) -> list:
+    if isinstance(value, (list, tuple)):
+        if len(value) != n:
+            raise ValueError(f"{name} has {len(value)} entries, expected {n}")
+        return list(value)
+    return [value] * n
+
+
+def _lanes(sims, plans, capacity_overrides, profiled, max_cycles,
+           n: Optional[int] = None) -> List[tuple]:
+    """Lane tuples.  Each argument is a sequence with one entry a lane (all
+    sequences agree on length) or a scalar broadcast to every lane; ``n``
+    forces the lane count when everything is scalar."""
+    args = {"sims": sims, "plans": plans,
+            "capacity_overrides": capacity_overrides, "profiled": profiled,
+            "max_cycles": max_cycles}
+    if n is None:
+        n = max((len(v) for v in args.values()
+                 if isinstance(v, (list, tuple))), default=1)
+    return list(zip(*(_broadcast(v, n, k) for k, v in args.items())))
+
+
+def run_sim_batch(
+    sim: CompiledSim, *,
+    plans: Union[None, FaultPlan, Sequence[Optional[FaultPlan]]] = None,
+    capacity_overrides: Union[
+        None, Dict[Edge, int], Sequence[Optional[Dict[Edge, int]]]] = None,
+    profiled: Union[bool, Sequence[bool]] = False,
+    max_cycles: Union[int, Sequence[int]] = 200_000,
+    n: Optional[int] = None,
+) -> List[SimResult]:
+    """Run B fault/capacity/profiled lanes of one machine as a single
+    vmapped device program.
+
+    Any of ``plans`` / ``capacity_overrides`` / ``profiled`` / ``max_cycles``
+    may be a sequence (all sequences must agree on length) or a scalar
+    (broadcast).  ``n`` forces the lane count when everything is scalar.
+    Results are bit-identical to calling :func:`run_sim` per lane.
+    """
+    return _launch(_lanes(sim, plans, capacity_overrides, profiled,
+                          max_cycles, n))
+
+
 def _trace_stride(stride: Optional[int], max_cycles: int, windows: int) -> int:
     if stride is not None:
         if stride < 1:
@@ -568,27 +595,15 @@ def run_sim_traced(
 ) -> Tuple[SimResult, TraceBuffers]:
     """One run with windowed occupancy capture.
 
-    The result is bit-identical to :func:`run_sim_single`; the extra
+    The result is bit-identical to :func:`run_sim`; the extra
     :class:`TraceBuffers` holds per-window per-edge occupancy max/sum and
     full/empty cycle counts.  ``windows`` is static (one executable per
     distinct value — keep it at the default unless you need finer time
     resolution); ``stride`` defaults to ``ceil(max_cycles / windows)``.
     """
-    with jax.profiler.TraceAnnotation("sim.pack"):
-        plan = faults or FaultPlan()
-        bucket = machine_bucket(sim, _stall_slots(plan))
-        machine = _to_device(pack_machine(sim, bucket))
-        ops, cap_np, idle_limit = pack_faults(
-            sim, bucket, plan, capacity_overrides, profiled, max_cycles)
-        ops = _to_device(ops)
     stride = _trace_stride(stride, max_cycles, windows)
-    jit_one, _ = _traced_jits(windows)
-    _STATS["launches"] += 1
-    _STATS["lanes"] += 1
-    outs = [np.asarray(o) for o in jit_one(machine, ops, jnp.int32(stride))]
-    with jax.profiler.TraceAnnotation("sim.unpack"):
-        res = _unpack(sim, cap_np, faults, profiled, idle_limit, outs[:7])
-        return res, _trim_trace(sim, stride, res.cycles, outs[7:])
+    return _launch([(sim, faults, capacity_overrides, profiled, max_cycles)],
+                   (windows, stride))[0]
 
 
 def run_sim_traced_batch(
@@ -606,40 +621,9 @@ def run_sim_traced_batch(
     ``max_cycles`` / stride so their window axes line up (lane-to-lane
     diffing needs a common time base).
     """
-    lengths = [len(v) for v in (plans, capacity_overrides, profiled)
-               if isinstance(v, (list, tuple))]
-    if n is None:
-        n = max(lengths) if lengths else 1
-    plans_l = _broadcast(plans, n, "plans")
-    caps_l = _broadcast(capacity_overrides, n, "capacity_overrides")
-    prof_l = _broadcast(profiled, n, "profiled")
     stride = _trace_stride(stride, max_cycles, windows)
-    if n == 1:
-        return [run_sim_traced(
-            sim, profiled=prof_l[0], max_cycles=max_cycles,
-            faults=plans_l[0], capacity_overrides=caps_l[0],
-            windows=windows, stride=stride)]
-
-    with jax.profiler.TraceAnnotation("sim.pack"):
-        stall_slots = max(_stall_slots(p or FaultPlan()) for p in plans_l)
-        bucket = machine_bucket(sim, stall_slots)
-        machine = _to_device(pack_machine(sim, bucket))
-        packed = [pack_faults(sim, bucket, p or FaultPlan(), c, pr,
-                              max_cycles)
-                  for p, c, pr in zip(plans_l, caps_l, prof_l)]
-        stacked = _stack([ops for ops, _, _ in packed])
-    _, jit_b = _traced_jits(windows)
-    _STATS["launches"] += 1
-    _STATS["lanes"] += n
-    outs = [np.asarray(o) for o in jit_b(machine, stacked, jnp.int32(stride))]
-    results = []
-    with jax.profiler.TraceAnnotation("sim.unpack"):
-        for b in range(n):
-            res = _unpack(sim, packed[b][1], plans_l[b], prof_l[b],
-                          packed[b][2], [o[b] for o in outs[:7]])
-            results.append((res, _trim_trace(sim, stride, res.cycles,
-                                             [o[b] for o in outs[7:]])))
-    return results
+    return _launch(_lanes(sim, plans, capacity_overrides, profiled,
+                          max_cycles, n), (windows, stride))
 
 
 def run_sim_many(
@@ -655,40 +639,18 @@ def run_sim_many(
 
     Used by the sweep drivers: a seed sweep or a timing sweep over
     same-shaped graphs becomes one device program instead of B serial runs.
-    Machines in singleton buckets fall back to the single-run path (still
+    Machines in singleton buckets run as single lanes (still
     compile-cached).  Results come back in input order.
     """
-    n = len(sims)
-    plans_l = _broadcast(plans, n, "plans")
-    caps_l = _broadcast(capacity_overrides, n, "capacity_overrides")
-    prof_l = _broadcast(profiled, n, "profiled")
-    mc_l = _broadcast(max_cycles, n, "max_cycles")
-    stall_slots = max(_stall_slots(p or FaultPlan()) for p in plans_l)
-
+    lanes = _lanes(list(sims), plans, capacity_overrides, profiled,
+                   max_cycles)
+    stall_slots = max(_stall_slots(lane[1] or FaultPlan()) for lane in lanes)
     groups: Dict[ShapeBucket, List[int]] = {}
-    for i, sim in enumerate(sims):
-        groups.setdefault(machine_bucket(sim, stall_slots), []).append(i)
+    for i, lane in enumerate(lanes):
+        groups.setdefault(machine_bucket(lane[0], stall_slots), []).append(i)
 
-    results: List[Optional[SimResult]] = [None] * n
-    for bucket, idxs in groups.items():
-        if len(idxs) == 1:
-            i = idxs[0]
-            results[i] = run_sim_single(
-                sims[i], profiled=prof_l[i], max_cycles=mc_l[i],
-                faults=plans_l[i], capacity_overrides=caps_l[i])
-            continue
-        with jax.profiler.TraceAnnotation("sim.pack"):
-            machines = _stack([pack_machine(sims[i], bucket) for i in idxs])
-            packed = [pack_faults(sims[i], bucket, plans_l[i] or FaultPlan(),
-                                  caps_l[i], prof_l[i], mc_l[i])
-                      for i in idxs]
-            stacked = _stack([ops for ops, _, _ in packed])
-        _STATS["launches"] += 1
-        _STATS["lanes"] += len(idxs)
-        outs = [np.asarray(o) for o in _jit_machines(machines, stacked)]
-        with jax.profiler.TraceAnnotation("sim.unpack"):
-            for b, i in enumerate(idxs):
-                results[i] = _unpack(
-                    sims[i], packed[b][1], plans_l[i], prof_l[i],
-                    packed[b][2], [o[b] for o in outs])
+    results: List[Optional[SimResult]] = [None] * len(lanes)
+    for idxs in groups.values():
+        for i, res in zip(idxs, _launch([lanes[i] for i in idxs])):
+            results[i] = res
     return results  # type: ignore[return-value]

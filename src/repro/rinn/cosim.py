@@ -21,9 +21,7 @@ import numpy as np
 from .graphgen import RinnGraph
 from .hls import TimingProfile
 from .batchsim import run_sim_batch, run_sim_many
-from .streamsim import (
-    CompiledSim, FaultPlan, SimResult, compile_graph, run_sim,
-)
+from .streamsim import CompiledSim, FaultPlan, SimResult, compile_graph
 
 Edge = Tuple[str, str]
 
@@ -169,21 +167,6 @@ class RemediationAttempt:
     report: Optional[DeadlockReport]
 
 
-def _remediation_bounds(sim: CompiledSim, faults: Optional[FaultPlan]):
-    """Shared sizing-state for the remediation loops: worst-case capacity
-    bounds, fault-adjusted base capacities, and in-edge sibling groups."""
-    node_of = {nid: i for i, nid in enumerate(sim.node_ids)}
-    bound = {e: max(2, int(sim.total_out[node_of[e[0]]]))
-             for e in sim.edge_list}
-    base_cap = {e: sim.capacity for e in sim.edge_list}
-    for cf in (faults.capacities if faults else ()):
-        base_cap[cf.edge] = cf.capacity
-    in_of: Dict[str, List[Edge]] = {}
-    for e in sim.edge_list:
-        in_of.setdefault(e[1], []).append(e)
-    return bound, base_cap, in_of
-
-
 def _ladder_overrides(ever_full, bound, base_cap, growth: int,
                       exponent: int) -> Dict[Edge, int]:
     """Rung ``exponent`` of the geometric ladder: every edge ever seen full
@@ -222,10 +205,64 @@ def _statically_safe_seed(
     return out
 
 
+def _ladder(
+    sim: CompiledSim, profiled: Tuple[bool, ...], *, max_cycles: int,
+    faults: Optional[FaultPlan], budget: int, growth: int,
+    seed: Dict[Edge, int],
+) -> Tuple[List[SimResult], List[RemediationAttempt], Dict[Edge, int]]:
+    """The remediation ladder of :func:`run_with_remediation` over one lane
+    per ``profiled`` flag: every rung is one launch of all lanes under one
+    shared capacity map, and a failed attempt reports the first incomplete
+    lane.  Returns the last results, the attempt log and their map.
+    """
+    node_of = {nid: i for i, nid in enumerate(sim.node_ids)}
+    bound = {e: max(2, int(sim.total_out[node_of[e[0]]]))
+             for e in sim.edge_list}
+    base_cap = {e: sim.capacity for e in sim.edge_list}
+    for cf in (faults.capacities if faults else ()):
+        base_cap[cf.edge] = cf.capacity
+    base_cap.update(seed)
+    in_of: Dict[str, List[Edge]] = {}
+    for e in sim.edge_list:
+        in_of.setdefault(e[1], []).append(e)
+
+    def launch(overrides):
+        n = len(profiled)
+        return run_sim_batch(
+            sim, plans=[faults] * n, profiled=list(profiled),
+            capacity_overrides=[overrides] * n, max_cycles=max_cycles)
+
+    ever_full: set = set()
+    attempts: List[RemediationAttempt] = []
+    overrides = dict(seed)
+    results = launch(overrides)
+    for k in range(budget):
+        reports = [diagnose(sim, r) for r in results if not r.completed]
+        if not reports:
+            break
+        if not any(rep.capacity_induced for rep in reports):
+            attempts.append(RemediationAttempt(
+                attempt=k, overrides={}, completed=False, report=reports[0]))
+            break
+        # a full merge input means the consumer's whole in-edge group shares
+        # the skew — grow siblings together instead of rediscovering them
+        # one deadlock at a time
+        for rep in reports:
+            for e in rep.full_edges:
+                ever_full |= set(in_of[e[1]])
+        overrides = {**seed, **_ladder_overrides(ever_full, bound, base_cap,
+                                                 growth, k + 1)}
+        results = launch(overrides)
+        stuck = [r for r in results if not r.completed]
+        attempts.append(RemediationAttempt(
+            attempt=k, overrides=overrides, completed=not stuck,
+            report=diagnose(sim, stuck[0]) if stuck else None))
+    return results, attempts, overrides
+
+
 def run_with_remediation(
     sim: CompiledSim, *, profiled: bool = False, max_cycles: int = 200_000,
     faults: Optional[FaultPlan] = None, budget: int = 6, growth: int = 2,
-    speculative: bool = True,
     initial_overrides: Optional[Dict[Edge, int]] = None,
     static_precheck: bool = False,
 ) -> Tuple[SimResult, List[RemediationAttempt]]:
@@ -233,9 +270,10 @@ def run_with_remediation(
 
     Sizing loop: every edge ever observed at capacity is grown geometrically
     per attempt (``base * growth**attempt``), capped at its worst-case demand
-    bound.  Stops early when the deadlock is not capacity-induced
-    (starvation from a dropped beat cannot be sized away) or the budget is
-    spent.  Returns the last result plus the attempt log; never raises.
+    bound, one launch a rung.  Stops early when the deadlock is not
+    capacity-induced (starvation from a dropped beat cannot be sized away)
+    or the budget is spent.  Returns the last result plus the attempt log;
+    never raises.
 
     ``initial_overrides`` seeds the capacity map before the first run — the
     trace-analysis hook (:func:`repro.trace.recommend_capacities`): when the
@@ -251,64 +289,14 @@ def run_with_remediation(
     only) simulator launch completes and the reactive ladder is skipped
     entirely: zero attempts, zero wasted deadlocked runs.  A ``safe``
     verdict launches unchanged, knowing no ladder will be needed.
-
-    ``speculative=True`` (default) runs the *whole remaining capacity
-    ladder* as one vmapped batch per diagnosis instead of one serial run
-    per rung, then walks the rungs in order, re-speculating only when a new
-    deadlock discovers FIFOs the frozen ladder did not grow.  Chosen
-    capacities, results, and the attempt log are identical to the serial
-    loop (``speculative=False``); only the launch count changes.
     """
-    bound, base_cap, in_of = _remediation_bounds(sim, faults)
     seed = dict(initial_overrides or {})
     if static_precheck:
         seed = _statically_safe_seed(sim, faults=faults, seed=seed,
                                      profiled=profiled)
-    base_cap.update(seed)
-
-    ever_full: set = set()
-    attempts: List[RemediationAttempt] = []
-    res = run_sim(sim, profiled=profiled, max_cycles=max_cycles,
-                  faults=faults, capacity_overrides=seed or None)
-    # speculative ladder state: rung results precomputed for a frozen
-    # ever_full set; invalidated whenever the set grows
-    spec_frozen: Optional[set] = None
-    spec_rungs: Dict[int, Tuple[Dict[Edge, int], SimResult]] = {}
-    for k in range(budget):
-        if res.completed:
-            break
-        report = diagnose(sim, res)
-        if not report.capacity_induced:
-            attempts.append(RemediationAttempt(
-                attempt=k, overrides={}, completed=False, report=report))
-            break
-        # a full merge input means the consumer's whole in-edge group shares
-        # the skew — grow siblings together instead of rediscovering them
-        # one deadlock at a time
-        for e in report.full_edges:
-            ever_full |= set(in_of[e[1]])
-        if speculative:
-            if spec_frozen != ever_full:
-                spec_frozen = set(ever_full)
-                exps = list(range(k + 1, budget + 1))
-                over_list = [
-                    {**seed, **_ladder_overrides(spec_frozen, bound,
-                                                 base_cap, growth, x)}
-                    for x in exps]
-                rung_res = run_sim_batch(
-                    sim, plans=[faults] * len(exps),
-                    capacity_overrides=over_list, profiled=profiled,
-                    max_cycles=max_cycles)
-                spec_rungs = dict(zip(exps, zip(over_list, rung_res)))
-            overrides, res = spec_rungs[k + 1]
-        else:
-            overrides = {**seed, **_ladder_overrides(ever_full, bound,
-                                                     base_cap, growth, k + 1)}
-            res = run_sim(sim, profiled=profiled, max_cycles=max_cycles,
-                          faults=faults, capacity_overrides=overrides)
-        attempts.append(RemediationAttempt(
-            attempt=k, overrides=overrides, completed=res.completed,
-            report=None if res.completed else diagnose(sim, res)))
+    (res,), attempts, _ = _ladder(
+        sim, (profiled,), max_cycles=max_cycles, faults=faults,
+        budget=budget, growth=growth, seed=seed)
     return res, attempts
 
 
@@ -327,42 +315,10 @@ def remediate_pair(
     (see :func:`run_with_remediation`).  Returns ``(ref, prof, attempts,
     capacities)``.
     """
-    bound, base_cap, in_of = _remediation_bounds(sim, faults)
-    seed = dict(initial_overrides or {})
-    base_cap.update(seed)
-
-    def pair(overrides):
-        ref, prof = run_sim_batch(
-            sim, plans=[faults, faults], profiled=[False, True],
-            capacity_overrides=[overrides, overrides],
-            max_cycles=max_cycles)
-        return ref, prof
-
-    ever_full: set = set()
-    attempts: List[RemediationAttempt] = []
-    overrides: Dict[Edge, int] = dict(seed)
-    ref, prof = pair(overrides)
-    for k in range(budget):
-        if ref.completed and prof.completed:
-            break
-        reports = [diagnose(sim, r) for r in (ref, prof) if not r.completed]
-        if not any(rep.capacity_induced for rep in reports):
-            attempts.append(RemediationAttempt(
-                attempt=k, overrides=dict(overrides), completed=False,
-                report=reports[0]))
-            break
-        for rep in reports:
-            for e in rep.full_edges:
-                ever_full |= set(in_of[e[1]])
-        overrides = {**seed, **_ladder_overrides(ever_full, bound, base_cap,
-                                                 growth, k + 1)}
-        ref, prof = pair(overrides)
-        done = ref.completed and prof.completed
-        attempts.append(RemediationAttempt(
-            attempt=k, overrides=overrides, completed=done,
-            report=None if done else diagnose(
-                sim, ref if not ref.completed else prof)))
-    return ref, prof, attempts, overrides
+    (ref, prof), attempts, capacities = _ladder(
+        sim, (False, True), max_cycles=max_cycles, faults=faults,
+        budget=budget, growth=growth, seed=dict(initial_overrides or {}))
+    return ref, prof, attempts, capacities
 
 
 @dataclasses.dataclass
@@ -484,29 +440,18 @@ def compare(graph: RinnGraph, timing: TimingProfile,
         static_certificate = decision.certificate
     attempts: List[RemediationAttempt] = []
     capacities: Dict[Edge, int] = {}
-    trace_ref = trace_prof = None
-    if auto_remediate:
+    if auto_remediate or not trace:
         # joint remediation: one capacity map, both lanes batched per rung —
         # Table-I rows always compare the same hardware config
         ref, prof, attempts, capacities = remediate_pair(
             sim, max_cycles=max_cycles, faults=faults,
-            budget=remediation_budget)
-        if trace and ref.completed and prof.completed:
-            from repro.trace.capture import trace_pair
-            ((ref, trace_ref), (prof, trace_prof)) = trace_pair(
-                sim, max_cycles=max_cycles, faults=faults,
-                capacity_overrides=capacities or None,
-                windows=trace_windows)
-    elif trace:
+            budget=remediation_budget if auto_remediate else 0)
+    trace_ref = trace_prof = None
+    if trace and (not auto_remediate or (ref.completed and prof.completed)):
         from repro.trace.capture import trace_pair
         ((ref, trace_ref), (prof, trace_prof)) = trace_pair(
             sim, max_cycles=max_cycles, faults=faults,
-            windows=trace_windows)
-    else:
-        # the unprofiled+profiled pair is one batched device program
-        ref, prof = run_sim_batch(
-            sim, plans=[faults, faults], profiled=[False, True],
-            max_cycles=max_cycles)
+            capacity_overrides=capacities or None, windows=trace_windows)
     for res in (ref, prof):
         if not res.completed:
             raise DeadlockError(diagnose(sim, res))
@@ -532,13 +477,9 @@ def cosim_only(graph: RinnGraph, timing: TimingProfile,
                auto_remediate: bool = False,
                remediation_budget: int = 6) -> SimResult:
     sim = compile_graph(graph, timing)
-    if auto_remediate:
-        res, _ = run_with_remediation(
-            sim, profiled=False, max_cycles=max_cycles, faults=faults,
-            budget=remediation_budget)
-    else:
-        res = run_sim(sim, profiled=False, max_cycles=max_cycles,
-                      faults=faults)
+    res, _ = run_with_remediation(
+        sim, max_cycles=max_cycles, faults=faults,
+        budget=remediation_budget if auto_remediate else 0)
     if not res.completed:
         raise DeadlockError(diagnose(sim, res))
     return res

@@ -298,28 +298,3 @@ class SimResult:
     node_produced: Dict[str, int] = dataclasses.field(default_factory=dict)
     faults: Optional[FaultPlan] = None
 
-
-def run_sim(
-    sim: CompiledSim, profiled: bool = False, max_cycles: int = 200_000,
-    faults: Optional[FaultPlan] = None,
-    capacity_overrides: Optional[Dict[Tuple[str, str], int]] = None,
-) -> SimResult:
-    """Execute the dataflow machine; pure JAX control flow inside.
-
-    ``faults`` injects the plan's stalls / beat faults / capacity faults /
-    profile-word bit flips; ``capacity_overrides`` grows or shrinks specific
-    edges' FIFOs (the remediation hook — it wins over the plan's capacity
-    faults).  A no-progress detector stops the loop once no actor has fired
-    for longer than any legitimate quiet period, so deadlocks terminate in
-    O(deadlock cycle) rather than O(max_cycles).
-
-    Fault plans, capacities, and the ``profiled`` flag are *runtime
-    arguments* of a jit-cached executable keyed on the padded machine shape
-    (see :mod:`repro.rinn.batchsim`): re-running on the same shape bucket
-    with a different plan / override / flag does not recompile.
-    """
-    from .batchsim import run_sim_single  # deferred: batchsim imports us
-
-    return run_sim_single(sim, profiled=profiled, max_cycles=max_cycles,
-                          faults=faults,
-                          capacity_overrides=capacity_overrides)

@@ -15,9 +15,7 @@ from __future__ import annotations
 import math
 from typing import Dict, List, Optional, Tuple
 
-from repro.rinn.batchsim import (
-    run_sim_single, run_sim_traced, run_sim_traced_batch,
-)
+from repro.rinn.batchsim import run_sim, run_sim_traced, run_sim_traced_batch
 from repro.rinn.streamsim import CompiledSim, FaultPlan, SimResult
 
 from .store import Edge, TraceStore
@@ -25,7 +23,7 @@ from .store import Edge, TraceStore
 
 def _calibrated_stride(sim: CompiledSim, windows: int, max_cycles: int,
                        profiled, faults, capacity_overrides) -> int:
-    probe = run_sim_single(
+    probe = run_sim(
         sim, profiled=bool(profiled) if not isinstance(profiled, (list, tuple))
         else any(profiled),
         max_cycles=max_cycles, faults=faults,

@@ -2,8 +2,8 @@
 
 Covers the PR-7 acceptance criteria: batched-vs-sequential bit-identical
 results, compile-cache hits across fault plans / capacity overrides /
-``profiled`` flags (trace-counter assertions), speculative parallel
-remediation matching the serial loop, the shared-capacity ``compare``
+``profiled`` flags (trace-counter assertions), the remediation ladder
+matching a rung-by-rung reference loop, the shared-capacity ``compare``
 fix, multi-machine bucketed sweeps, and critical-path fault biasing.
 """
 import pytest
@@ -11,9 +11,10 @@ import pytest
 from repro.rinn import (
     BeatFault, CapacityFault, FaultPlan, RinnConfig, ZCU102, compare,
     compile_graph, compile_stats, cosim_many, critical_path_actors,
-    critical_path_edges, generate_rinn, machine_bucket, run_sim,
+    critical_path_edges, diagnose, generate_rinn, machine_bucket, run_sim,
     run_sim_batch, run_sim_many, run_with_remediation,
 )
+from repro.rinn.cosim import _ladder_overrides
 
 
 def skip_cfg(seed=1, n_backbone=6, **kw):
@@ -130,37 +131,63 @@ def test_batch_launch_counts(sim):
 
 
 # --------------------------------------------------------------------- #
-# speculative parallel remediation == serial grow-and-retry
+# the remediation ladder == a rung-by-rung loop of run_sim
 # --------------------------------------------------------------------- #
-def test_speculative_remediation_matches_serial(sim4):
-    r_ser, a_ser = run_with_remediation(sim4, speculative=False)
-    r_spec, a_spec = run_with_remediation(sim4, speculative=True)
-    assert r_ser.completed and r_spec.completed
-    assert r_ser.cycles == r_spec.cycles
-    assert r_ser.fifo_max == r_spec.fifo_max
-    assert r_ser.fifo_capacity == r_spec.fifo_capacity
-    assert [a.attempt for a in a_ser] == [a.attempt for a in a_spec]
-    assert [a.overrides for a in a_ser] == [a.overrides for a in a_spec]
-    assert [a.completed for a in a_ser] == [a.completed for a in a_spec]
+def _reference_ladder(sim, faults, budget=6, growth=2):
+    """Grow-and-retry with one ``run_sim`` a rung; attempts as
+    ``(attempt, overrides, completed)``."""
+    node_of = {nid: i for i, nid in enumerate(sim.node_ids)}
+    bound = {e: max(2, int(sim.total_out[node_of[e[0]]]))
+             for e in sim.edge_list}
+    base = {e: sim.capacity for e in sim.edge_list}
+    base.update({cf.edge: cf.capacity
+                 for cf in (faults.capacities if faults else ())})
+    ever_full, attempts = set(), []
+    res = run_sim(sim, faults=faults)
+    for k in range(budget):
+        if res.completed:
+            break
+        report = diagnose(sim, res)
+        if not report.capacity_induced:
+            attempts.append((k, {}, False))
+            break
+        ever_full |= {e for e in sim.edge_list
+                      if any(e[1] == f[1] for f in report.full_edges)}
+        overrides = _ladder_overrides(ever_full, bound, base, growth, k + 1)
+        res = run_sim(sim, faults=faults, capacity_overrides=overrides)
+        attempts.append((k, overrides, res.completed))
+    return res, attempts
 
 
-def test_speculative_remediation_gives_up_on_starvation(sim):
+@pytest.mark.parametrize("case", ["small_fifos", "fault_capacity"])
+def test_remediation_matches_reference_ladder(sim, sim4, case):
+    if case == "small_fifos":
+        machine, plan = sim4, None
+    else:
+        base = run_sim(sim)
+        edge = max(base.fifo_max, key=base.fifo_max.get)
+        machine = sim
+        plan = FaultPlan(capacities=(CapacityFault(edge=edge, capacity=1),))
+    res, attempts = run_with_remediation(machine, faults=plan)
+    ref, ref_attempts = _reference_ladder(machine, plan)
+    assert ref_attempts, "the ladder must fire"
+    assert res.completed == ref.completed
+    assert res.cycles == ref.cycles
+    assert res.fifo_max == ref.fifo_max
+    assert res.fifo_capacity == ref.fifo_capacity
+    assert [(a.attempt, a.overrides, a.completed)
+            for a in attempts] == ref_attempts
+    if case == "small_fifos":
+        assert res.completed
+
+
+def test_remediation_ladder_gives_up_on_starvation(sim):
     e = sim.edge_list[2]
     plan = FaultPlan(drops=(BeatFault(edge=e, beat=3),))
-    res, attempts = run_with_remediation(sim, faults=plan, speculative=True)
+    res, attempts = run_with_remediation(sim, faults=plan)
     assert not res.completed
     assert len(attempts) == 1  # one diagnosis, no futile sizing attempts
     assert not attempts[-1].report.capacity_induced
-
-
-def test_speculative_remediation_with_fault_capacity(sim):
-    base = run_sim(sim)
-    edge = max(base.fifo_max, key=base.fifo_max.get)
-    plan = FaultPlan(capacities=(CapacityFault(edge=edge, capacity=1),))
-    r_ser, a_ser = run_with_remediation(sim, faults=plan, speculative=False)
-    r_spec, a_spec = run_with_remediation(sim, faults=plan, speculative=True)
-    assert r_ser.completed == r_spec.completed
-    assert [a.overrides for a in a_ser] == [a.overrides for a in a_spec]
 
 
 # --------------------------------------------------------------------- #

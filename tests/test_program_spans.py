@@ -102,12 +102,11 @@ def sim():
 
 def _sim_calls(sim):
     import repro.rinn as rinn
-    from repro.rinn import batchsim
 
     plans = [rinn.FaultPlan.generate(sim, seed=s, n_stalls=1)
              for s in range(3)]
     return {
-        "single": lambda: batchsim.run_sim_single(sim, faults=plans[0]),
+        "single": lambda: rinn.run_sim(sim, faults=plans[0]),
         "batch": lambda: rinn.run_sim_batch(sim, plans=plans),
         "batch_of_one": lambda: rinn.run_sim_batch(sim, plans=plans[:1]),
         "traced": lambda: rinn.run_sim_traced(sim, max_cycles=4000),

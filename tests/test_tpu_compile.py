@@ -203,7 +203,8 @@ def test_simulator_campaign_program_compiles(one_chip):
     lanes = jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct((256,) + x.shape, x.dtype,
                                        sharding=one_chip), ops)
-    compiled = batchsim._jit_lanes.lower(machine, lanes).compile()
+    compiled = batchsim._program(None, (None, 0)).lower(
+        machine, lanes).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 2**30
 
 
