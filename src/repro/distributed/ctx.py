@@ -62,3 +62,25 @@ def shard_act(x, *axes):
     mesh, rules = ctx
     spec = logical_pspec(rules, axes, shape=x.shape, mesh=mesh)
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+
+
+def per_batch_shard(fn, batch: int, in_batch, out_batch):
+    """``fn`` run on each device's share of the batch when a mesh is
+    active, else ``fn`` itself.  A Pallas TPU kernel has no partitioning
+    rule: the compiler refuses one whose operands are sharded.
+    ``in_batch`` and ``out_batch`` give each argument's and result's batch
+    dimension (None: replicated); a batch of ``batch`` rows splits as the
+    ``"batch"`` rule maps it onto the mesh, as ``shard_act`` would
+    constrain it."""
+    ctx = _CTX.get()
+    if ctx is None:
+        return fn
+    mesh, rules = ctx
+    names = logical_pspec(rules, ("batch",), shape=(batch,), mesh=mesh)[0]
+
+    def specs(dims):
+        return tuple(P() if d is None else P(*[None] * d, names)
+                     for d in dims)
+
+    return jax.shard_map(fn, mesh=mesh, in_specs=specs(in_batch),
+                         out_specs=specs(out_batch), check_vma=False)

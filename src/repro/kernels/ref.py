@@ -75,3 +75,20 @@ def tile_absmax_reference(a, b, block_m: int, block_n: int):
     M, N = out.shape
     tiles = out.reshape(M // block_m, block_m, N // block_n, block_n)
     return jnp.max(jnp.abs(tiles), axis=(1, 3))
+
+
+def mla_decode_reference(q, caches, layer, pos, *, values: int,
+                         scale: float):
+    """Absorbed decode attention over layer ``layer`` of the stacked latent
+    caches, sliced and narrowed to the query's width, with every position
+    scored and those past ``pos`` masked: (out [B, H, values], largest
+    scaled logit per sequence and head [B, H])."""
+    cache = jax.lax.dynamic_index_in_dim(caches, layer, keepdims=False)
+    cache = cache[..., :q.shape[-1]]                         # [B, S, c]
+    logits = jnp.einsum("bhc,bsc->bhs", q.astype(cache.dtype), cache,
+                        preferred_element_type=jnp.float32) * scale
+    valid = jnp.arange(cache.shape[1]) < pos + 1
+    logits = jnp.where(valid, logits, -1e30)
+    w = jax.nn.softmax(logits, axis=-1).astype(cache.dtype)
+    out = jnp.einsum("bhs,bsr->bhr", w, cache[..., :values])
+    return out, jnp.max(logits, axis=-1)

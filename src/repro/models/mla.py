@@ -16,7 +16,12 @@ Two paths compute the same attention:
     ordinary attention with q/k wider than v;
   * absorbed (decode): the cache holds only ``[c_kv | k_pe]`` per position
     and layer; ``W_UK`` is folded into the query and ``W_UV`` into the
-    output, so the cache is never decompressed.
+    output, so the cache is never decompressed.  The scores and the value
+    sum run in one Pallas kernel (``kernels.mla_decode``) that reads the
+    layer's rows from the caches stacked over layers, one position block
+    at a time and only the blocks up to the decoded position.  It reads
+    the rows whole, padding included; the query's padding is zero, so the
+    padding lanes count for nothing.
 
 Rotary pairs are interleaved (``common.apply_rotary``); the published code
 de-interleaves before ``rotate_half``.  The two differ by a fixed
@@ -25,13 +30,16 @@ leaves every score unchanged.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Dict
 
 import jax
 import jax.numpy as jnp
 
-from .attention import NEG_INF, attention
+from ..distributed.ctx import per_batch_shard
+from ..kernels.mla_decode import mla_decode
+from .attention import attention
 from .common import apply_rotary, rms_norm, write_in_place
 from .params import ParamSpec
 
@@ -91,29 +99,33 @@ def mla_apply_train(cfg, p, x, positions):
     return out.reshape(B, T, -1) @ p["wo"], lmax, latent
 
 
+def _latent_attention(q, caches, layer, pos, *, values, scale):
+    """``kernels.mla_decode`` on each device's share of the batch where a
+    mesh is active (the decode caches are sharded over the batch)."""
+    return per_batch_shard(
+        functools.partial(mla_decode, values=values, scale=scale),
+        q.shape[0], in_batch=(0, 1, None, None), out_batch=(0, 0),
+    )(q, caches, layer, pos)
+
+
 @jax.named_scope("attn")
 def mla_apply_decode(cfg, p, x, caches, layer, pos):
     """Absorbed path: one token against layer ``layer`` of the latent caches
     stacked over layers, [L, B, Smax, row] (rows of ``r + dr`` padded to
     the lane width).  Writes position ``pos`` of that layer in place, then
-    reads the layer back.  Returns (out, logit_max, caches)."""
+    attends to positions ``0..pos`` of it with ``kernels.mla_decode``,
+    which reads the stacked buffer itself, one position block at a time.
+    Returns (out, logit_max, caches)."""
     B = x.shape[0]
     H, r, dn = cfg.n_heads, cfg.kv_lora_rank, cfg.qk_nope_dim
     positions = jnp.full((B, 1), pos, jnp.int32)
     q_nope, q_pe, latent = _project(cfg, p, x, positions)
     with jax.named_scope("latent_update"):
         caches = write_in_place(caches, latent[None], (layer, 0, pos, 0))
-    cache = jax.lax.dynamic_index_in_dim(caches, layer, keepdims=False)
-    cache = cache[..., :latent.shape[-1]]                    # [B, Smax, r+dr]
     wkv_b = p["wkv_b"].reshape(r, H, -1)
     q_lat = jnp.einsum("bthn,rhn->bthr", q_nope, wkv_b[..., :dn])
-    q_cat = jnp.concatenate([q_lat, q_pe], axis=-1)         # [B, 1, H, r+dr]
-    logits = jnp.einsum("bthc,bsc->bhts", q_cat, cache,
-                        preferred_element_type=jnp.float32) * _scale(cfg)
-    valid = jnp.arange(cache.shape[1]) < pos + 1
-    logits = jnp.where(valid, logits, NEG_INF)
-    lmax = jnp.max(logits)
-    w = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
-    o_lat = jnp.einsum("bhts,bsr->bthr", w, cache[..., :r])
-    out = jnp.einsum("bthr,rhv->bthv", o_lat, wkv_b[..., dn:])
-    return out.reshape(B, 1, -1) @ p["wo"], lmax, caches
+    q_cat = jnp.concatenate([q_lat, q_pe], axis=-1)[:, 0]   # [B, H, r+dr]
+    o_lat, logit_max = _latent_attention(q_cat, caches, layer, pos,
+                                         values=r, scale=_scale(cfg))
+    out = jnp.einsum("bhr,rhv->bhv", o_lat, wkv_b[..., dn:])
+    return out.reshape(B, 1, -1) @ p["wo"], jnp.max(logit_max), caches

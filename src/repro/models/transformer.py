@@ -430,7 +430,9 @@ def latent_row(cfg) -> int:
     """A latent cache row: ``kv_lora_rank + qk_rope_dim`` padded to a
     multiple of 128.  The TPU keeps a minor dimension of that width minor,
     so the cache stays row-major on the device, as ``write_in_place``
-    needs; the padding is written once, as zeros, and never read."""
+    needs.  The padding is written once, as zeros; decode attention
+    (``kernels.mla_decode``) reads it with the row and weighs it by the
+    query's zero padding."""
     return -(-cfg.latent_dim // 128) * 128
 
 
@@ -458,11 +460,12 @@ def lm_decode_step(cfg, params, caches, tokens, pos):
 
     The position-indexed caches (``KvCaches``, ``LatentCaches``) ride in
     the layer scan's carry, and each layer writes its one position into
-    the stacked buffer in place before reading its layer back.  Passed
-    through the scan's ``xs`` and ``ys`` instead, they would be a second
-    stacked buffer that every layer rewrites a whole slice of and that is
-    copied back into the donated cache after the loop.  An ``SsmCache``
-    changes whole every step, so it is scanned over as ``xs`` and ``ys``.
+    the stacked buffer in place before its attention reads the layer.
+    Passed through the scan's ``xs`` and ``ys`` instead, they would be a
+    second stacked buffer that every layer rewrites a whole slice of and
+    that is copied back into the donated cache after the loop.  An
+    ``SsmCache`` changes whole every step, so it is scanned over as ``xs``
+    and ``ys``.
 
     Returns (logits [B, 1, V], caches, rows).
     """

@@ -11,6 +11,7 @@ from _hypothesis_shim import given, settings, st
 
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention
+from repro.kernels.mla_decode import POSITION_BLOCK, cache_block, mla_decode
 from repro.kernels.moe_dispatch import moe_dispatch
 from repro.kernels.profiled_matmul import profiled_matmul
 from repro.kernels.ssd_scan import ssd_state_passing
@@ -71,6 +72,74 @@ def test_property_flash_attention_shapes(t, d, seed):
     want, _ = ref.mha_reference(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=5e-5, atol=5e-5)
+
+
+# --------------------------------------------------------------------- #
+# latent-attention decode
+# --------------------------------------------------------------------- #
+GARBAGE = 1e30      # large and finite: what the kernel must never weigh
+
+
+@pytest.mark.parametrize("stack,layer", [(1, 0), (3, 2)])
+@pytest.mark.parametrize("s", [384, 200])
+@pytest.mark.parametrize("attended", [1, 128, 129, "all"])
+def test_mla_decode_matches_reference(stack, layer, s, attended):
+    """One layer of a stacked latent cache (a one-layer ``prefix`` stack,
+    or layer 2 of a ``blocks`` stack), rows of 40 values padded to 128, in
+    blocks of 128 positions over 384, or over 200 where the last block
+    runs past the cache: the positions past ``pos`` and the padding lanes
+    hold garbage that must not reach the output."""
+    b, h, c, values, row = 4, 4, 40, 32, 128
+    attended = s if attended == "all" else attended
+    pos = attended - 1
+    caches = rnd(0, stack, b, s, row)
+    caches = caches.at[..., c:].set(GARBAGE).at[:, :, pos + 1:].set(GARBAGE)
+    q = rnd(1, b, h, c)
+    out, row_max = mla_decode(q, caches, layer, pos, values=values,
+                              scale=0.125, **I)
+    want, want_max = ref.mla_decode_reference(q, caches, layer, pos,
+                                              values=values, scale=0.125)
+    assert out.shape == (b, h, values) and row_max.shape == (b, h)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(row_max), np.asarray(want_max),
+                               rtol=1e-6, atol=1e-6)
+    # the pipeline fetches a block when its index changes: count the
+    # changes of the cache's index map over the position axis
+    blk = POSITION_BLOCK
+    scalars = np.array([layer, pos], np.int32)
+    index = [tuple(int(v) for v in cache_block(0, j, scalars, blk=blk))
+             for j in range(-(-s // blk))]
+    fetched = 1 + sum(x != y for x, y in zip(index, index[1:]))
+    assert fetched == -(-attended // blk)
+    assert {i[0] for i in index} == {layer}
+
+
+def test_mla_decode_below_one_block_matches_reference():
+    """A cache shorter than a block is one block of its own length."""
+    caches = rnd(4, 2, 3, 72, 128).at[:, :, 50:].set(GARBAGE)
+    q = rnd(5, 3, 2, 64)
+    out, row_max = mla_decode(q, caches, 1, 49, values=64, scale=0.2, **I)
+    want, want_max = ref.mla_decode_reference(q, caches, 1, 49, values=64,
+                                              scale=0.2)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(row_max), np.asarray(want_max),
+                               rtol=1e-6, atol=1e-6)
+
+
+def test_mla_decode_bfloat16_matches_reference():
+    """The served precision: bfloat16 rows and query, float32 softmax."""
+    caches = rnd(2, 2, 8, 256, 128, dtype=jnp.bfloat16)
+    q = rnd(3, 8, 4, 72, dtype=jnp.bfloat16)
+    out, row_max = mla_decode(q, caches, 1, 200, values=64, scale=0.1, **I)
+    want, want_max = ref.mla_decode_reference(q, caches, 1, 200, values=64,
+                                              scale=0.1)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=2e-2, atol=2e-2)
+    np.testing.assert_allclose(np.asarray(row_max), np.asarray(want_max),
+                               rtol=1e-5, atol=1e-5)
 
 
 # --------------------------------------------------------------------- #

@@ -212,9 +212,9 @@ def test_moe_tap_reports_tokens_per_held_expert():
 
 
 def test_decode_step_scopes():
-    """Latent attention under ``attn`` (its cache write ``latent_update``),
-    the whole feed-forward slot under ``mlp`` with the MoE layer's
-    ``router``, ``experts`` and ``shared_experts``."""
+    """Latent attention under ``attn`` (its cache write ``latent_update``,
+    its kernel ``mla_decode``), the whole feed-forward slot under ``mlp``
+    with the MoE layer's ``router``, ``experts`` and ``shared_experts``."""
     import re
 
     from bench.metrics import _scopes
@@ -230,5 +230,67 @@ def test_decode_step_scopes():
     for path in ("attn/latent_update", "mlp/router", "mlp/experts",
                  "mlp/shared_experts", "mlp/dot_general"):
         assert re.search(rf'loc\("([^"]*/)?{path}', text), path
-    scopes = _scopes.instruction_scopes(lowered.compile().as_text())
+    compiled = lowered.compile().as_text()
+    scopes = _scopes.instruction_scopes(compiled)
     assert {"attn", "mlp", "norm", "embed", "logits"} <= set(scopes.values())
+    # the latent-attention kernel (interpreted off the TPU; on the TPU one
+    # custom call, tests/test_tpu_compile.py) counts under ``attn``
+    kernel = [_scopes.head(line) for line in compiled.splitlines()
+              if re.search(r'op_name="[^"]*attn/[^"]*mla_decode/', line)
+              and _scopes.head(line)]
+    assert kernel and all(scopes[k] == "attn" for k in kernel)
+
+
+MESH_SCRIPT = """
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import sys
+sys.path[:0] = ["src", "."]
+import jax, jax.numpy as jnp
+import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from repro.configs import get_config
+from repro.distributed import activation_sharding, default_rules
+from repro.models import init_params
+from repro.models.api import decode_fn, init_caches, model_specs
+
+cfg = get_config("moonlight-16b-a3b").reduced().with_expert_share(0, 8)
+params = init_params(model_specs(cfg), jax.random.PRNGKey(3))
+toks = jax.random.randint(jax.random.PRNGKey(4), (8, 6), 0, cfg.vocab_size)
+mesh = Mesh(np.array(jax.devices()).reshape(4, 1), ("data", "model"))
+
+def sharded(params, caches, tok, pos):
+    with activation_sharding(mesh, default_rules()):
+        return decode_fn(cfg, params, caches, tok, pos)
+
+step = jax.jit(sharded)
+one = jax.jit(lambda *a: decode_fn(cfg, *a))
+rows = NamedSharding(mesh, P(None, "data"))
+c_mesh = jax.device_put(init_caches(cfg, 8, 6), rows)
+c_one = init_caches(cfg, 8, 6)
+for t in range(6):
+    got, c_mesh, _ = step(params, c_mesh, toks[:, t:t + 1], t)
+    want, c_one, _ = one(params, c_one, toks[:, t:t + 1], t)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=1e-5, atol=1e-5)
+assert c_mesh.blocks.sharding.spec[1] == "data", c_mesh.blocks.sharding
+print("OK per-shard latent attention")
+"""
+
+
+def test_decode_on_a_mesh_attends_per_batch_shard(tmp_path):
+    """On four devices whose ``data`` axis splits the batch, as the decode
+    caches are sharded, the step, whose attention kernel runs on each
+    device's share of the sequences, gives the one-device step's logits
+    (that the kernel then needs no collective is compiled for a described
+    v5e in ``tests/test_tpu_compile.py``)."""
+    import subprocess
+    import sys
+
+    script = tmp_path / "mesh_decode.py"
+    script.write_text(MESH_SCRIPT)
+    res = subprocess.run([sys.executable, str(script)], cwd=ROOT,
+                         capture_output=True, text=True, timeout=600)
+    assert res.returncode == 0, res.stdout + res.stderr
+    assert "OK per-shard latent attention" in res.stdout

@@ -5,7 +5,7 @@ Pallas blocks not aligned to the (8, 128) tiling, kernels over their fast
 memory, programs larger than the chip's 16 GiB.  These tests compile, for
 one chip of a described ``v5e:2x2``, the full-width chatglm3-6b serve step
 and the Moonlight-16B-A3B share's, the per-leaf parameter init, the
-256-lane simulator campaign program and the four Pallas kernels at
+256-lane simulator campaign program and the five Pallas kernels at
 main-path widths, and read the compiled serve steps' cache traffic.  The
 topology is described inside a fixture, never at import, so every test
 worker collects the same tests and only the one given this file loads the
@@ -16,16 +16,20 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from bench.metrics import _scopes
 from repro.configs import get_config
 from repro.kernels.flash_attention import flash_attention
+from repro.kernels.mla_decode import mla_decode
 from repro.kernels.moe_dispatch import moe_dispatch
 from repro.kernels.profiled_matmul import profiled_matmul
 from repro.kernels.ssd_scan import ssd_state_passing
 from repro.models.api import init_caches, model_specs
 from repro.models.params import ParamSpec, _init_leaf, is_spec
+from repro.models.transformer import LatentCaches
 from repro.rinn import (FaultPlan, RinnConfig, ZCU102, compile_graph,
                         generate_rinn)
 from repro.rinn import batchsim
@@ -35,7 +39,7 @@ V5E_HBM = 16 * 2**30
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     with pytest.MonkeyPatch.context() as mp:
         mp.setenv("TPU_LOG_DIR", "disabled")   # no compiler logs outside
@@ -49,9 +53,14 @@ def one_chip():
         cache_on = jax.config.jax_enable_compilation_cache
         jax.config.update("jax_enable_compilation_cache", False)
         try:
-            yield SingleDeviceSharding(topo.devices[0])
+            yield topo
         finally:
             jax.config.update("jax_enable_compilation_cache", cache_on)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _abstract(tree, sharding):
@@ -68,7 +77,13 @@ SERVE_STEPS = {
     "moonlight-16b-a3b-share": (
         lambda: get_config("moonlight-16b-a3b").with_expert_share(0, 8),
         128, 640),
+    # the same at batch 32 and 2048 + 64 positions, which no block of 128
+    # divides: the attention kernel's last block runs past the cache
+    "moonlight-16b-a3b-share-2112": (
+        lambda: get_config("moonlight-16b-a3b").with_expert_share(0, 8),
+        32, 2112),
 }
+MOONLIGHT_STEPS = [n for n in sorted(SERVE_STEPS) if n.startswith("moon")]
 
 
 @pytest.fixture(scope="module")
@@ -148,7 +163,9 @@ def _update_elements(body, name):
 def test_serve_step_updates_caches_in_place(serve_steps, name):
     """The donated caches are the step's outputs, written one position a
     layer: no stacked cache is allocated anew, copied or rewritten whole,
-    and no layer's slice is copied to another layout."""
+    and no layer's slice is copied to another layout.  Latent attention
+    reads the stacked caches in its kernel: no instruction, fused or not,
+    holds one layer's latent rows at any row width."""
     compiled, n_weights, caches, max_len = serve_steps(name)
     text = compiled.as_text()
     aliased = re.findall(r'\((\d+), \{\}, (?:may|must)-alias\)',
@@ -161,7 +178,11 @@ def test_serve_step_updates_caches_in_place(serve_steps, name):
     one_position = max(layer) // max_len
     comps = _computations(text)
     fused = set(re.findall(r'calls=%([\w.\-]+)', text))
+    latent_rows = {c.shape[1:-1] for c in leaves
+                   if isinstance(caches, LatentCaches)}
     for comp, body in comps.items():
+        for inst, (dims, *_) in body.items():
+            assert dims[:-1] not in latent_rows, f"{comp}: {inst}"
         if comp in fused:
             continue
         for inst, (dims, op, _, rest, _) in body.items():
@@ -179,6 +200,52 @@ def test_serve_step_updates_caches_in_place(serve_steps, name):
                 assert _update_elements(called, root) <= one_position, where
             elif op == "dynamic-update-slice":
                 assert _update_elements(body, inst) <= one_position, where
+
+
+def test_latent_attention_kernel_counts_under_attn(serve_steps):
+    """The Moonlight step's latent attention is one Mosaic kernel a layer
+    (the dense layer's and the scanned stack's), at 640 positions and at
+    2112, and each maps to the ``attn`` scope that ``mla_decode_ms``
+    reads."""
+    for name in MOONLIGHT_STEPS:
+        text = serve_steps(name)[0].as_text()
+        scopes = _scopes.instruction_scopes(text)
+        calls = [_scopes.head(line) for line in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in line]
+        assert len(calls) == 2, name
+        assert all(scopes[c] == "attn" and "mla_decode" in c
+                   for c in calls), name
+
+
+def test_latent_attention_runs_per_batch_shard(topo):
+    """On a 2x2 mesh whose ``data`` axis splits the batch, as the decode
+    caches are sharded, each chip runs the kernel on its own half of the
+    sequences and of the cache's rows: one kernel and no collective."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.distributed import activation_sharding, default_rules
+    from repro.models.mla import _latent_attention
+
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+
+    def attend(q, caches, layer, pos):
+        with activation_sharding(mesh, default_rules()):
+            return _latent_attention(q, caches, layer, pos, values=512,
+                                     scale=192 ** -0.5)
+
+    def arg(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, P(*spec)))
+
+    text = jax.jit(attend).lower(
+        arg((128, 16, 576), jnp.bfloat16, "data"),
+        arg((26, 128, 640, 640), jnp.bfloat16, None, "data"),
+        arg((), jnp.int32), arg((), jnp.int32)).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1 and "bf16[26,64,640,640]" in calls[0]
+    assert not re.search(r"all-gather|all-to-all|all-reduce|"
+                         r"collective-permute", text)
 
 
 def test_param_init_never_holds_a_float32_leaf(one_chip):
@@ -213,6 +280,20 @@ KERNELS = {
     "flash_attention": (
         lambda q, k, v: flash_attention(q, k, v, causal=True),
         [((1, 8, 4096, 128), jnp.bfloat16)] * 3),
+    # moonlight-16b-a3b latent attention: 16 heads over layer 3 of the
+    # 26-layer stack of 640-wide rows at batch 128 and 640 positions
+    "mla_decode": (
+        lambda q, c, layer, pos: mla_decode(q, c, layer, pos, values=512,
+                                            scale=192 ** -0.5),
+        [((128, 16, 576), jnp.bfloat16), ((26, 128, 640, 640), jnp.bfloat16),
+         ((), jnp.int32), ((), jnp.int32)]),
+    # the same at batch 32 and 2048 + 64 positions: 17 blocks of 128, the
+    # last one running past the cache
+    "mla_decode_2112": (
+        lambda q, c, layer, pos: mla_decode(q, c, layer, pos, values=512,
+                                            scale=192 ** -0.5),
+        [((32, 16, 576), jnp.bfloat16), ((26, 32, 2112, 640), jnp.bfloat16),
+         ((), jnp.int32), ((), jnp.int32)]),
     # moonshot-v1-16b-a3b routing: 4096 tokens x top-6 over 64 experts
     "moe_dispatch": (
         lambda e: moe_dispatch(e, 64, 480),
